@@ -2,7 +2,7 @@
 
 interpret-mode correctness timing vs the jnp oracle, plus the kernel's
 modeled HBM traffic against the paper's Eq (10) and the tensor-size floor
-(this container is CPU-only; on TPU the same harness reports wall time).
+(the kernel runs in interpret mode, so its times are no device times).
 All planning/traffic numbers come from the engine planner — the same
 BlockPlan object the kernel executes.
 """

@@ -39,7 +39,7 @@ MODULES = (
     "tune",             # autotuner: search, warm-cache replay, calibration
     "tucker",           # Multi-TTM backends + Tucker/HOOI (arXiv:2207.10437)
     "lm_step",          # §Roofline: per-cell terms from the dry-run
-    "serve",            # serving layer: batched vs looped, cold vs warm
+    "serve",            # serving layer: batched vs looped, queue flush
 )
 
 JSON_SCHEMA_VERSION = 1

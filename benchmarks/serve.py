@@ -1,6 +1,6 @@
 """Benchmark: the serving layer's amortization claims, measured.
 
-Three tables:
+Two tables:
 
   * ``serve_batched_B{b}`` / ``serve_looped_B{b}`` — requests/sec of ONE
     batched ``cp_als_batched`` call on a B-stack vs a Python loop of B
@@ -10,23 +10,13 @@ Three tables:
     launch overhead; at B>=4 batched must be strictly faster.
   * ``serve_queue_B{b}`` — end-to-end ``DecompositionServer`` flush
     (bucketing + padding + batched execute) in requests/sec.
-  * ``serve_cold_compile`` / ``serve_warm_compile`` — the persistent
-    compilation cache (``ExecutionContext.compilation_cache``): a fresh
-    subprocess jit-compiles the bucket's batched program against an
-    empty cache directory (cold), a second fresh subprocess compiles the
-    identical program against the now-populated directory (warm; XLA
-    reloads from disk). Warm must be faster than cold.
 
 ``REPRO_BENCH_TINY=1`` shrinks shapes/batches for CI smoke runs.
 """
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -35,27 +25,6 @@ import jax.numpy as jnp
 SHAPE, RANK, ITERS = (16, 14, 12), 4, 5
 BATCHES = (1, 2, 4, 8)
 TINY_SHAPE, TINY_BATCHES = (10, 8, 6), (1, 4)
-
-_CHILD = """
-import json, sys, time
-import jax, jax.numpy as jnp
-from repro.engine.batch import cp_als_batched
-from repro.engine.context import ExecutionContext
-
-cache_dir, b, shape, rank, iters = (
-    sys.argv[1], int(sys.argv[2]), tuple(json.loads(sys.argv[3])),
-    int(sys.argv[4]), int(sys.argv[5]),
-)
-ctx = ExecutionContext.create(compilation_cache=cache_dir)
-ctx.ensure_compilation_cache()
-x = jax.random.normal(jax.random.PRNGKey(0), (b,) + shape)
-# tol=0: no per-iteration concretization, so the whole batched run is
-# one traceable (and therefore persistently cacheable) program
-run = jax.jit(lambda t: cp_als_batched(t, rank, n_iters=iters).weights)
-t0 = time.perf_counter()
-jax.block_until_ready(run(x))
-print(json.dumps({"first_call_s": time.perf_counter() - t0}))
-"""
 
 
 def _timed(fn, reps: int = 3) -> float:
@@ -66,20 +35,6 @@ def _timed(fn, reps: int = 3) -> float:
         jax.block_until_ready(fn())
         best = min(best, (time.perf_counter() - t0) * 1e6)
     return best
-
-
-def _compile_seconds(cache_dir: str, b: int, shape, rank, iters) -> float:
-    """First-call seconds of the bucket's jitted batched program in a
-    FRESH process pointed at ``cache_dir`` (subprocess: compilation
-    caches are process-global, so cold/warm needs process isolation)."""
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    out = subprocess.run(
-        [sys.executable, "-c", _CHILD, cache_dir, str(b),
-         json.dumps(list(shape)), str(rank), str(iters)],
-        capture_output=True, text=True, env=env, check=True,
-    )
-    return float(json.loads(out.stdout.strip().splitlines()[-1])["first_call_s"])
 
 
 def rows() -> list[tuple[str, float, str]]:
@@ -138,20 +93,6 @@ def rows() -> list[tuple[str, float, str]]:
             f"serve_queue_B{b}", us_queue,
             f"req_per_s={b / (us_queue * 1e-6):.1f}",
         ))
-
-    # cold vs warm persistent-compile split (fresh process each side)
-    b = batches[-1]
-    with tempfile.TemporaryDirectory() as cache_dir:
-        cold_s = _compile_seconds(cache_dir, b, shape, RANK, iters)
-        warm_s = _compile_seconds(cache_dir, b, shape, RANK, iters)
-    out.append((
-        "serve_cold_compile", cold_s * 1e6,
-        f"B={b} empty persistent cache",
-    ))
-    out.append((
-        "serve_warm_compile", warm_s * 1e6,
-        f"B={b} warm_speedup={cold_s / warm_s:.2f}x",
-    ))
     return out
 
 

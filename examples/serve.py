@@ -12,7 +12,8 @@ Three steps:
    one batched call per bucket.
 3. Warm starts — a context with ``compilation_cache=<dir>`` persists
    every compiled program, so the next process serving the same buckets
-   skips recompilation.
+   skips recompilation.  This demo keeps them in the checkout's
+   ``.cache/jax`` (``JAX_COMPILATION_CACHE_DIR``, when set, wins).
 
     PYTHONPATH=src python examples/serve.py
     REPRO_EX_TINY=1 PYTHONPATH=src python examples/serve.py   # CI smoke
@@ -20,7 +21,6 @@ Three steps:
 
 import os
 import sys
-import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 import repro
 from repro.core.tensor import random_low_rank_tensor
+from repro.engine.context import CHECKOUT_COMPILATION_CACHE
 from repro.launch.serve import DecompositionServer
 
 
@@ -57,31 +58,31 @@ def main():
     print(f"cp_als_batched: fits={[f'{f:.3f}' for f in res.fits]} "
           f"iters={[int(i) for i in res.n_iters]}")
 
-    # 2. the serving layer: mixed shapes, one batched call per bucket
-    with tempfile.TemporaryDirectory() as cache_dir:
-        # 3. warm starts: compiled programs persist in cache_dir
-        ctx = repro.ExecutionContext.create(
-            backend="auto", compilation_cache=cache_dir
+    # 2. the serving layer: mixed shapes, one batched call per bucket;
+    # 3. warm starts: compiled programs persist in the checkout's cache
+    ctx = repro.ExecutionContext.create(
+        backend="auto", compilation_cache=CHECKOUT_COMPILATION_CACHE
+    )
+    server = DecompositionServer(ctx, n_iters=n_iters, tol=1e-4)
+    for i in range(batch):
+        shape = tuple(d - i for d in dims)  # jitter: same bucket
+        t, _ = random_low_rank_tensor(
+            jax.random.PRNGKey(10 + i), shape, rank
         )
-        server = DecompositionServer(ctx, n_iters=n_iters, tol=1e-4)
-        for i in range(batch):
-            shape = tuple(d - i for d in dims)  # jitter: same bucket
-            t, _ = random_low_rank_tensor(
-                jax.random.PRNGKey(10 + i), shape, rank
-            )
-            server.submit(t, rank, request_id=f"req{i}")
-        results = server.flush()
-        buckets = {r.bucket for r in results.values()}
-        print(f"served {len(results)} mixed-shape requests in "
-              f"{len(buckets)} bucket(s):")
-        for rid in sorted(results):
-            r = results[rid]
-            print(f"  {rid}: shape->crop fit={r.fit:.4f} "
-                  f"iters={r.n_iters} batch={r.batch} "
-                  f"{'cold' if r.cold else 'warm'}")
-        n_cached = sum(len(fs) for _, _, fs in os.walk(cache_dir))
-        print(f"persistent compilation cache: {n_cached} program(s) "
-              f"saved for the next process")
+        server.submit(t, rank, request_id=f"req{i}")
+    results = server.flush()
+    buckets = {r.bucket for r in results.values()}
+    print(f"served {len(results)} mixed-shape requests in "
+          f"{len(buckets)} bucket(s):")
+    for rid in sorted(results):
+        r = results[rid]
+        print(f"  {rid}: shape->crop fit={r.fit:.4f} "
+              f"iters={r.n_iters} batch={r.batch} "
+              f"{'cold' if r.cold else 'warm'}")
+    cache_dir = ctx.ensure_compilation_cache()
+    n_cached = sum(len(fs) for _, _, fs in os.walk(cache_dir))
+    print(f"persistent compilation cache {cache_dir}: {n_cached} "
+          f"program(s) kept for the next process")
 
 
 if __name__ == "__main__":

@@ -1,9 +1,7 @@
-"""Version-tolerant shims for jax APIs that moved between releases.
+"""The jax mesh and ``shard_map`` calls every module of this repo shares.
 
-The repo targets current jax (``jax.shard_map``, ``jax.sharding.AxisType``)
-but must also run on the 0.4.x jaxlib baked into the validation container,
-where ``shard_map`` still lives in ``jax.experimental`` and meshes have no
-``axis_types``. All mesh/shard_map construction goes through here.
+All mesh and ``shard_map`` construction goes through here, so the axis
+types and the replication-check policy are set in one place.
 """
 
 from __future__ import annotations
@@ -11,66 +9,38 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-    _shard_map = jax.shard_map
-    _SHARD_MAP_HAS_CHECK_REP = False
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_HAS_CHECK_REP = True
+from jax.sharding import AbstractMesh, AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_rep: bool = True):
-    """``shard_map`` across jax versions.
-
-    ``check_rep=False`` is needed on 0.4.x for bodies containing primitives
-    whose replication rules are incomplete there (e.g. ``linalg.solve``);
-    newer jax has no such knob and needs none.
-    """
-    if _SHARD_MAP_HAS_CHECK_REP:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_rep,
-        )
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with ``check_rep`` naming its ``check_vma`` check
+    (the static varying-manual-axes analysis that proves ``out_specs``
+    replication).  Bodies that hold a ``pallas_call`` pass ``False``: its
+    ``out_shape`` carries no manual-axes type, which the check rejects."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_rep,
+    )
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict across jax versions
-    (older releases return a one-element list of per-device dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``compiled.cost_analysis()`` as a dict (empty when XLA reports
+    nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(names), axis_types=(AxisType.Auto,) * len(names)
-        )
-    return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names), axis_types=(AxisType.Auto,) * len(names)
+    )
 
 
 def make_abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """Device-free ``jax.sharding.AbstractMesh`` across jax versions.
+    """Device-free ``jax.sharding.AbstractMesh``.
 
-    0.4.x takes one ``((name, size), ...)`` tuple; newer releases take
-    separate ``axis_sizes``/``axis_names`` tuples. An abstract mesh
-    carries only the logical grid — enough to trace a ``shard_map``
-    program with ``jax.make_jaxpr`` on a single-device host (the AOT
-    path ``repro.verify.comm`` uses), never to run it.
+    An abstract mesh carries only the logical grid — enough to trace a
+    ``shard_map`` program with ``jax.make_jaxpr`` on a single-device host
+    (the AOT path ``repro.verify.comm`` uses), never to run it.
     """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:  # pragma: no cover - version-dependent
-        return AbstractMesh(tuple(shape), tuple(names))
+    return AbstractMesh(tuple(shape), tuple(names))

@@ -315,16 +315,22 @@ def multi_ttm_blocked_feasible_b(
     ndim: int, ranks: Sequence[int], block: int, mem: int
 ) -> bool:
     """Eq-9 analog for Multi-TTM: the blocked working set
-    b^N (tensor tile) + b*sum R_d (matrix tiles) + b^{N-1}*prod R_d
-    (Kronecker weight block) + b*prod R_d (output tile) must fit in M."""
+    b^N (tensor tile) + b*sum R_d (matrix tiles) + b*prod R_d (output
+    tile) + the Kronecker weight applied one b-row slab at a time —
+    b*R' for the slab's block W[c, (r_1..r_{N-2})] (R' = prod of all
+    but the last rank), b^2*R' for its copy over the b output rows, and
+    b^2*R_{N-1} for the slab's product with the last matrix — must fit
+    in M.  The full b^{N-1}*prod R_d Kronecker block is never resident."""
     r = 1
     for x in ranks:
         r *= x
+    r_lead = r // ranks[-1] if ranks else 1
     ws = (
         block ** ndim
         + block * sum(ranks)
-        + block ** (ndim - 1) * r
         + block * r
+        + block * r_lead * (1 + block)
+        + block * block * (ranks[-1] if ranks else 1)
     )
     return ws <= mem
 

@@ -97,11 +97,15 @@ def hosvd_init(
     x: jax.Array, ranks: Sequence[int], dtype=jnp.float32
 ) -> list[jax.Array]:
     """HOSVD factors: the top-``R_k`` left singular vectors of every
-    unfolding ``X_(k)``, via the ``I_k x I_k`` Gram eigendecomposition."""
+    unfolding ``X_(k)``, via the ``I_k x I_k`` Gram eigendecomposition.
+
+    Each Gram ``X_(k) X_(k)^T`` is one contraction over the other modes;
+    no unfolding is materialized, so the device holds at most one
+    compiler-made copy of ``x`` beside it."""
     factors = []
     for k, r in enumerate(ranks):
-        xm = _unfold_rows(x, k)
-        gram = xm @ xm.T
+        others = tuple(a for a in range(x.ndim) if a != k)
+        gram = jnp.tensordot(x, x, axes=(others, others))
         factors.append(_leading_eigvecs(gram, int(r)).astype(x.dtype))
     return factors
 

@@ -265,8 +265,9 @@ def build_cp_sweep(
         _sweep_local, ndim=ndim, local_fn=local_fn,
         compute_fit=compute_fit, overlap=overlap,
     )
-    # check_rep=False: the body contains linalg.solve (no replication rule
-    # on 0.4.x) and, under backend="pallas"/"auto", pallas_call
+    # check_rep=False: the replicated outputs (Grams, weights, fit) are
+    # not provable by the manual-axes check, and under
+    # backend="pallas"/"auto" the body holds pallas_call
     return jax.jit(
         shard_map(
             body,

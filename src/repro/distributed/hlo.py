@@ -180,16 +180,16 @@ def collective_bytes(compiled_or_text: Any) -> int:
 # --------------------------------------------------------------------------
 
 #: jaxpr primitive name -> HLO collective kind. ``psum`` maps to
-#: all-reduce (under shard_map it lowers to one); ``psum2`` is the
-#: replication-checked rewrite shard_map's ``check_rep=True`` emits on
-#: jax 0.4.x — same collective, same bytes; ``ppermute`` to
+#: all-reduce (under shard_map it lowers to one); ``psum_invariant`` is
+#: the form a ``psum`` takes under shard_map's ``check_vma=True`` — same
+#: collective, same bytes; ``ppermute`` to
 #: collective-permute. ``pmean`` has no primitive of its own (it traces
 #: to psum + divide), so the map is complete for this repo's programs.
 JAXPR_COLLECTIVE_PRIMS: dict[str, str] = {
     "all_gather": "all-gather",
     "reduce_scatter": "reduce-scatter",
     "psum": "all-reduce",
-    "psum2": "all-reduce",
+    "psum_invariant": "all-reduce",
     "ppermute": "collective-permute",
     "all_to_all": "all-to-all",
 }
@@ -210,7 +210,7 @@ def _group_size(prim: str, params: Mapping[str, Any],
                 axis_sizes: Mapping[str, int]) -> int:
     if prim in ("all_gather", "reduce_scatter", "all_to_all"):
         return int(params["axis_size"])
-    if prim in ("psum", "psum2"):
+    if prim in ("psum", "psum_invariant"):
         q = 1
         for a in params.get("axes", ()):
             if isinstance(a, str):

@@ -178,20 +178,17 @@ def _stationary_local(
 
 def _resolve_parallel_ctx(api: str, ctx, backend, interpret):
     """Shared ctx/legacy resolution for the Alg 3/4 builders, plus the
-    replication-check policy: pallas_call has no shard_map replication
-    rule on older jax, so the (purely diagnostic) rep check is skipped
-    when the local body may contain a kernel ("auto" can resolve to
-    pallas at trace time). ``ctx.distribution.check_rep`` overrides."""
+    replication-check policy: a ``pallas_call`` output carries no
+    manual-axes type, so the (purely diagnostic) check is skipped when
+    the local body may contain a kernel ("auto" can resolve to pallas at
+    trace time)."""
     from ..engine.context import context_from_legacy
 
     ctx = context_from_legacy(
         api, ctx, {"backend": backend, "interpret": interpret},
         stacklevel=4,
     )
-    check_rep = ctx.backend not in ("pallas", "auto")
-    if ctx.distribution is not None and ctx.distribution.check_rep is not None:
-        check_rep = ctx.distribution.check_rep
-    return ctx, check_rep
+    return ctx, ctx.backend not in ("pallas", "auto")
 
 
 def mttkrp_stationary(

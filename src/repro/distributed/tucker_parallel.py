@@ -244,8 +244,9 @@ def build_tucker_sweep(
         _tucker_sweep_local, ndim=ndim, ranks=ranks, grid=grid,
         local_fn=local_fn, compute_fit=compute_fit, overlap=overlap,
     )
-    # check_rep=False: the body contains eigh (no replication rule) and,
-    # under backend="pallas"/"auto", pallas_call
+    # check_rep=False: the replicated factors and fit are not provable by
+    # the manual-axes check, and under backend="pallas"/"auto" the body
+    # holds pallas_call
     return jax.jit(
         shard_map(
             body,

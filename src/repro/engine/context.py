@@ -7,14 +7,14 @@ parallel).  Three PRs in, that machine description had fragmented into a
 kwarg soup: every driver (``engine.execute.mttkrp``, ``contract_partial``,
 the dimension tree, ``cp_als``/``cp_gradient``, Algorithms 3/4, the
 distributed sweep) re-declared and re-validated
-``backend/memory/interpret/tune/check_rep/mesh/grid/procs`` with drifting
+``backend/memory/interpret/tune/mesh/grid/procs`` with drifting
 error messages.  This module replaces all of that:
 
 * :class:`ExecutionContext` — a frozen, hashable dataclass bundling the
   full execution environment: backend choice, :class:`~.plan.Memory`,
   dtype policy, ``interpret``, the tuning policy (``tune`` + plan-cache
   handle), and a :class:`Distribution` sub-config (grid/procs/mesh,
-  ``check_rep``).  Built once, validated once (eagerly, in
+  ``overlap``).  Built once, validated once (eagerly, in
   ``__post_init__`` — so every construction path validates), consumed
   everywhere.
 * :meth:`ExecutionContext.create` — the single constructor every driver's
@@ -44,6 +44,7 @@ import json
 import os
 import warnings
 from dataclasses import dataclass, field, fields, replace
+from pathlib import Path
 from typing import Any, Mapping, Sequence
 
 from .plan import BlockPlan, Memory, MultiTTMPlan
@@ -138,8 +139,8 @@ def check_driver_options(
 @dataclass(frozen=True)
 class Distribution:
     """The parallel-machine description (§V): processor grid, count, the
-    optional rank-axis extent ``p0`` (Algorithm 4), and the shard_map
-    replication-check policy.
+    optional rank-axis extent ``p0`` (Algorithm 4), and the collective
+    schedule ``overlap``.
 
     ``mesh`` is a process-local device handle: it is excluded from
     equality/hash/serialization (a context round-trips through JSON by its
@@ -150,7 +151,6 @@ class Distribution:
     grid: tuple[int, ...] | None = None
     procs: int | None = None
     p0: int = 1
-    check_rep: bool | None = None
     overlap: str = "none"
     mesh: Any = field(default=None, compare=False, repr=False)
 
@@ -178,7 +178,6 @@ class Distribution:
             "grid": list(self.grid) if self.grid is not None else None,
             "procs": self.procs,
             "p0": self.p0,
-            "check_rep": self.check_rep,
             "overlap": self.overlap,
         }
 
@@ -189,7 +188,6 @@ class Distribution:
             grid=tuple(grid) if grid is not None else None,
             procs=d.get("procs"),
             p0=int(d.get("p0", 1)),
-            check_rep=d.get("check_rep"),
             overlap=str(d.get("overlap", "none")),
         )
 
@@ -286,6 +284,15 @@ class PlanDecision:
 # The context
 # ---------------------------------------------------------------------------
 
+#: The checkout's own persistent compile-cache directory (git-ignored).
+#: The server, ``examples/serve.py`` and ``chip_smoke.py`` point their
+#: contexts' ``compilation_cache`` here; it is a fixed path, because the
+#: path is part of what makes a cached program hit again.
+CHECKOUT_COMPILATION_CACHE = str(
+    Path(__file__).resolve().parents[3] / ".cache" / "jax"
+)
+
+
 @dataclass(frozen=True)
 class ExecutionContext:
     """The full execution environment, as one immutable, hashable value.
@@ -381,25 +388,29 @@ class ExecutionContext:
         """Point JAX's persistent compilation cache at this context's
         ``compilation_cache`` directory (no-op when the field is None).
 
-        Sets the process-global JAX config — cache dir plus the two
-        thresholds that would otherwise skip small CPU programs — so
-        every compile after this call is written to (and on a warm
-        start, read from) the directory. Idempotent; returns the
-        directory actually configured. This is the MaxText
-        microbenchmark warm-start pattern: a fresh process pays zero
-        recompiles for buckets an earlier process already served.
+        ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX already reads
+        it, so no directory is configured here and that one is returned.
+        Otherwise this sets the process-global JAX config — cache dir plus
+        the two thresholds that would otherwise skip small programs — so
+        every compile after this call is written to (and on a warm start,
+        read from) the directory.  Idempotent; returns the directory
+        actually in use.  A fresh process then pays no recompiles for
+        buckets an earlier process already served.
         """
         if self.compilation_cache is None:
             return None
         import jax
 
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if env_dir:
+            return env_dir
         os.makedirs(self.compilation_cache, exist_ok=True)
         already = (
             jax.config.jax_compilation_cache_dir == self.compilation_cache
         )
         jax.config.update("jax_compilation_cache_dir", self.compilation_cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         if not already:
             # the persistent-cache singleton is memoized at the process's
             # FIRST compile; without a reset, a dir configured after that
@@ -428,7 +439,6 @@ class ExecutionContext:
         grid: Sequence[int] | None = None,
         procs: int | None = None,
         p0: int = 1,
-        check_rep: bool | None = None,
         overlap: str = "none",
         observe: bool = False,
         compilation_cache: str | None = None,
@@ -451,7 +461,7 @@ class ExecutionContext:
                     p0 = mesh.shape["r"]
             dist = Distribution(
                 grid=tuple(grid) if grid is not None else None,
-                procs=procs, p0=p0, check_rep=check_rep, overlap=overlap,
+                procs=procs, p0=p0, overlap=overlap,
                 mesh=mesh,
             )
         if out_dtype is not None and not isinstance(out_dtype, str):
@@ -870,7 +880,7 @@ _DEFAULT_MEMO: dict[str, "ExecutionContext"] = {}
 
 _CREATE_KEYS = (
     {f.name for f in fields(ExecutionContext)}
-    | {"distributed", "mesh", "grid", "procs", "p0", "check_rep", "overlap"}
+    | {"distributed", "mesh", "grid", "procs", "p0", "overlap"}
 ) - {"distribution", "problem", "decisions"}
 
 

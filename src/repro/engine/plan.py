@@ -425,15 +425,23 @@ class MultiTTMPlan:
         return x_tile + m_tiles + out
 
     def weight_scratch_words(self) -> int:
-        """Fast-memory words of the Kronecker weight block
-        ``prod(bc) * prod(R_d)`` built in VMEM each grid step (never
-        materialized in HBM)."""
-        return math.prod(self.block_contract) * math.prod(self.ranks)
+        """Fast-memory words the kernel builds in VMEM for one slab of
+        the tile (never materialized in HBM): the slab's Kronecker block
+        ``W[c_{k-1}, (r_1..r_{k-1})]`` and its copy broadcast over the
+        ``bi`` rows for the batched contraction, plus the slab's product
+        with the minor matrix, ``bi * c_{k-1} * R_k``.  The kernel applies
+        the Kronecker weight one ``c_{k-1}`` slab at a time, so the full
+        ``prod(bc) * prod(R_d)`` block is never resident."""
+        c_sub = self.block_contract[-2] if len(self.block_contract) > 1 else 1
+        r_lead = math.prod(self.ranks[:-1])
+        return c_sub * (r_lead * (1 + self.block_i)
+                        + self.block_i * self.ranks[-1])
 
     def working_set_words(self) -> int:
         """Fast-memory words per grid step: tensor tile + matrix tiles +
-        Kronecker weight block + output tile (the Multi-TTM Eq-9 analog;
-        uniform-b form in ``core.bounds.multi_ttm_blocked_feasible_b``)."""
+        output tile + the per-slab Kronecker scratch (the Multi-TTM Eq-9
+        analog; uniform-b form in
+        ``core.bounds.multi_ttm_blocked_feasible_b``)."""
         return self.kernel_block_words() + self.weight_scratch_words()
 
     def fits(self, memory: Memory) -> bool:

@@ -9,11 +9,13 @@ adapt (DESIGN.md §3):
 
 * the tensor block is a (bi, bj, bk) VMEM tile (HBM→VMEM via BlockSpec);
 * the N-ary multiplies are *restructured* (atomicity broken, as §V-C3
-  licenses) into an MXU contraction: the Khatri-Rao block
-  W[(j,k), r] = A(j,r)·B(k,r) is formed **in VMEM** from bj·br + bk·br
+  licenses) into an MXU contraction against the Khatri-Rao block
+  W[(j,k), r] = A(j,r)·B(k,r), applied **in VMEM** from its bj·br + bk·br
   words — never materialized in HBM (this is precisely the paper's "the KRP
-  has few parameters" insight) — and the tile update is one matmul
-      O(bi×br) += X(bi × bj·bk) @ W(bj·bk × br);
+  has few parameters" insight). The tile update
+      O(bi×br) += Σ_j A(j,r) · (X(bi·bj × bk) @ B(bk × br))
+  does the MXU work of one X(bi × bj·bk) @ W matmul without flattening
+  the tile across its (sublane, lane) axes, which Mosaic refuses;
 * the output tile O(bi, br) is *output-stationary*: the grid iterates the
   contraction dims (j, k) innermost so O accumulates in VMEM across the
   whole (j, k) sweep and is written back once per (i, r) tile — Algorithm
@@ -37,19 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU compiler params are only importable with a TPU-capable jaxlib
-    from jax.experimental.pallas import tpu as pltpu
-
-    if hasattr(pltpu, "CompilerParams"):
-        _COMPILER_PARAMS = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")
-        )
-    else:  # pragma: no cover - older naming
-        _COMPILER_PARAMS = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary")
-        )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+from .common import compiler_params
+from .mttkrpn import krp_contract
 
 
 def _mttkrp3_kernel(x_ref, a_ref, b_ref, o_ref, *, acc_dtype):
@@ -68,21 +59,8 @@ def _mttkrp3_kernel(x_ref, a_ref, b_ref, o_ref, *, acc_dtype):
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    bi, bj, bk = x_ref.shape
-    br = a_ref.shape[1]
-    # Form the Khatri-Rao block in VMEM: W[(j,k), r] = A(j,r) * B(k,r).
-    w = (
-        a_ref[...].astype(acc_dtype)[:, None, :]
-        * b_ref[...].astype(acc_dtype)[None, :, :]
-    ).reshape(bj * bk, br)
-    # Matricize the tensor tile and hit the MXU.
-    xm = x_ref[...].reshape(bi, bj * bk)
-    o_ref[...] += jax.lax.dot_general(
-        xm,
-        w,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
+    # (bi*bj, bk) @ B on the MXU, then the A(j, r) weights on the VPU
+    o_ref[...] += krp_contract(x_ref, (a_ref, b_ref), acc_dtype)
 
 
 def mttkrp3_pallas(
@@ -115,9 +93,6 @@ def mttkrp3_pallas(
         k_sz // block_k,
     )
     kernel = functools.partial(_mttkrp3_kernel, acc_dtype=acc_dtype)
-    kwargs = {}
-    if _COMPILER_PARAMS is not None and not interpret:
-        kwargs["compiler_params"] = _COMPILER_PARAMS
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -131,5 +106,5 @@ def mttkrp3_pallas(
         out_specs=pl.BlockSpec((block_i, block_r), lambda i, r, j, k: (i, r)),
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(2, 2),
     )(x, a, b)

@@ -7,9 +7,11 @@ per r-tile, and the rank-structured weight block
 
     W[(c_1..c_{N-1}), r] = Π_k A_k(c_k, r)
 
-is built in VMEM by chained broadcasts (the Khatri-Rao structure — never
-materialized in HBM). See mttkrp3.py for the full TPU-adaptation rationale;
-this module generalizes it to arbitrary order for 4-/5-way tensors.
+is applied factor by factor in VMEM (:func:`krp_contract`: the minor
+factor on the MXU, the others as Khatri-Rao row weights on the VPU), so
+it is never materialized in HBM. See mttkrp3.py for the full
+TPU-adaptation rationale; this module generalizes it to arbitrary order
+for 4-/5-way tensors.
 """
 
 from __future__ import annotations
@@ -21,17 +23,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
+from .common import compiler_params, fori_leading, mxu_dot, row
 
-    def _compiler_params(n_contract: int):
-        sem = ("parallel", "parallel") + ("arbitrary",) * n_contract
-        if hasattr(pltpu, "CompilerParams"):
-            return pltpu.CompilerParams(dimension_semantics=sem)
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)  # pragma: no cover
-except Exception:  # pragma: no cover
-    def _compiler_params(n_contract: int):
-        return None
+
+def krp_contract(x_ref, f_refs, acc_dtype, p_ref=None) -> jax.Array:
+    """One tile's MTTKRP contribution ``sum_c X(i, c..) prod_d A_d(c_d, r)``.
+
+    ``x_ref`` is the ``(bi, c_1..c_k)`` tensor tile, ``f_refs`` the k
+    ``(c_d, br)`` factor tiles.  For each index of the leading contraction
+    axes ``c_1..c_{k-2}`` the ``(bi, c_{k-1}, c_k)`` slab meets the MXU
+    once, ``T = slab x_k A_k`` of shape ``(bi, c_{k-1}, br)``, and the
+    Khatri-Rao weights of the other axes reduce ``T`` on the VPU.  The MXU
+    does the same multiply-adds as one matmul against the full Khatri-Rao
+    block would, and the tile is never flattened across its (sublane,
+    lane) axes.  ``p_ref``, when given, accumulates ``T`` itself (the
+    fused pair kernel's second output).  Returns the ``(bi, br)`` sum."""
+    if len(f_refs) == 1:  # one contraction axis: a plain matmul
+        return mxu_dot(x_ref[...], f_refs[0][...]).astype(acc_dtype)
+    bi, c_sub, c_min = x_ref.shape[0], x_ref.shape[-2], x_ref.shape[-1]
+    br = f_refs[0].shape[1]
+    lead_refs, f_sub, f_min = f_refs[:-2], f_refs[-2], f_refs[-1]
+
+    def body(idx, acc):
+        at = (slice(None),) + tuple(idx)
+        slab = x_ref[at]
+        t = mxu_dot(slab.reshape(bi * c_sub, c_min), f_min[...])
+        t = t.reshape(bi, c_sub, br)
+        if p_ref is not None:
+            p_ref[at] += t.astype(p_ref.dtype)
+        w = f_sub[...].astype(acc_dtype)
+        for f, a in zip(lead_refs, idx):
+            w = w * row(f, a, acc_dtype)
+        return acc + jnp.sum(t.astype(acc_dtype) * w[None], axis=1)
+
+    return fori_leading(
+        x_ref.shape[1:-2], body, jnp.zeros((bi, br), acc_dtype)
+    )
 
 
 def _kernel(*refs, n_contract: int, acc_dtype):
@@ -47,18 +74,7 @@ def _kernel(*refs, n_contract: int, acc_dtype):
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    br = f_refs[0].shape[1]
-    # chained outer product over the contraction tile dims
-    w = f_refs[0][...].astype(acc_dtype)  # (b1, br)
-    for f in f_refs[1:]:
-        ft = f[...].astype(acc_dtype)  # (bd, br)
-        w = (w[:, None, :] * ft[None, :, :]).reshape(-1, br)
-    bi = x_ref.shape[0]
-    xm = x_ref[...].reshape(bi, -1)
-    o_ref[...] += jax.lax.dot_general(
-        xm, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
+    o_ref[...] += krp_contract(x_ref, f_refs, acc_dtype)
 
 
 def _partial_kernel(*refs, n_contract: int, acc_dtype):
@@ -67,8 +83,10 @@ def _partial_kernel(*refs, n_contract: int, acc_dtype):
         O(i, r) += sum_c X(i, c_1..c_k, r) * prod_d A_d(c_d, r)
 
     Same output-stationary schedule as :func:`_kernel`, but the tensor tile
-    carries the rank axis, so the weight block combines elementwise along r
-    (a VPU reduce, not an MXU matmul)."""
+    carries the rank axis, so the weights combine elementwise along r (a
+    VPU reduce, not an MXU matmul).  The tile is walked eight sublane rows
+    of ``c_k`` at a time (all of ``c_k`` when it is not a multiple of
+    eight), so the temporaries stay one ``(bi, 8, br)`` slab."""
     x_ref = refs[0]
     f_refs = refs[1 : 1 + n_contract]
     o_ref = refs[1 + n_contract]
@@ -81,14 +99,25 @@ def _partial_kernel(*refs, n_contract: int, acc_dtype):
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    br = f_refs[0].shape[1]
-    w = f_refs[0][...].astype(acc_dtype)  # (b1, br)
-    for f in f_refs[1:]:
-        ft = f[...].astype(acc_dtype)  # (bd, br)
-        w = (w[:, None, :] * ft[None, :, :]).reshape(-1, br)
-    bi = x_ref.shape[0]
-    xm = x_ref[...].astype(acc_dtype).reshape(bi, -1, br)
-    o_ref[...] += jnp.sum(xm * w[None, :, :], axis=1)
+    bi, c_min, br = x_ref.shape[0], x_ref.shape[-2], x_ref.shape[-1]
+    chunk = 8 if c_min % 8 == 0 else c_min
+
+    def body(idx, acc):
+        *lead, j = idx
+        start = j * chunk
+        if not isinstance(start, int):
+            start = pl.multiple_of(start, chunk)
+        rows = pl.ds(start, chunk)
+        slab = x_ref[(slice(None),) + tuple(lead) + (rows, slice(None))]
+        w = f_refs[-1][rows, :].astype(acc_dtype)
+        for f, a in zip(f_refs[:-1], lead):
+            w = w * row(f, a, acc_dtype)
+        return acc + jnp.sum(slab.astype(acc_dtype) * w[None], axis=1)
+
+    o_ref[...] += fori_leading(
+        x_ref.shape[1:-2] + (c_min // chunk,), body,
+        jnp.zeros((bi, br), acc_dtype),
+    )
 
 
 def mttkrp_partial_pallas(
@@ -141,10 +170,6 @@ def mttkrp_partial_pallas(
     kernel = functools.partial(
         _partial_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    kwargs = {}
-    cp = _compiler_params(nc)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -152,7 +177,7 @@ def mttkrp_partial_pallas(
         out_specs=pl.BlockSpec((block_i, block_r), o_map),
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(2, nc),
     )(x, *factors)
 
 
@@ -201,10 +226,6 @@ def mttkrpn_pallas(
         for d in range(nc)
     ]
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
-    kwargs = {}
-    cp = _compiler_params(nc)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -212,5 +233,5 @@ def mttkrpn_pallas(
         out_specs=pl.BlockSpec((block_i, block_r), o_map),
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(2, nc),
     )(x, *factors)

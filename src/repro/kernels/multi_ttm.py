@@ -12,9 +12,12 @@ weight block
 
     W[(c_1..c_k), (r_1..r_k)] = prod_d A_d(c_d, r_d)
 
-built in VMEM by chained outer products — the rank-structured analog of
-the MTTKRP kernels' Khatri-Rao weight (separate small rank axes here,
-one shared rank axis there), never materialized in HBM.  The Tucker
+applied in VMEM factor by factor — the rank-structured analog of the
+MTTKRP kernels' Khatri-Rao weight (separate small rank axes here, one
+shared rank axis there), never materialized in HBM: A_k on the MXU
+against each tensor slab, then the Kronecker product of the other
+factors' tiles (built one ``c_{k-1}`` slab at a time) in a second,
+batched MXU contraction.  The Tucker
 ranks are kept whole per tile (they are the small dimensions of the
 problem); only the tensor modes are blocked, planned by
 :class:`repro.engine.plan.MultiTTMPlan`.
@@ -23,23 +26,32 @@ problem); only the tensor modes are blocked, planned by
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
+from .common import compiler_params, fori_leading, mxu_dot, row
 
-    def _compiler_params(n_contract: int):
-        sem = ("parallel",) + ("arbitrary",) * n_contract
-        if hasattr(pltpu, "CompilerParams"):
-            return pltpu.CompilerParams(dimension_semantics=sem)
-        return pltpu.TPUCompilerParams(dimension_semantics=sem)  # pragma: no cover
-except Exception:  # pragma: no cover
-    def _compiler_params(n_contract: int):
-        return None
+
+def _spread(r: int, s: int, outer: bool) -> jax.Array:
+    """0/1 matrix that moves column ``a`` of an ``r``-wide (``outer``) or
+    ``s``-wide operand to every column ``(a, *)`` resp. ``(*, a)`` of the
+    C-order ``r*s`` Kronecker column space."""
+    rows = r if outer else s
+    q = jax.lax.broadcasted_iota(jnp.int32, (rows, r * s), 1)
+    a = jax.lax.broadcasted_iota(jnp.int32, (rows, r * s), 0)
+    return ((q // s if outer else q % s) == a).astype(jnp.float32)
+
+
+def _kron_cols(u: jax.Array, m: jax.Array) -> jax.Array:
+    """``W[c, (a, b)] = u[0, a] * m[c, b]`` for a ``(1, r)`` row ``u`` and
+    a ``(c, s)`` tile ``m``.  The column expansion is two exact 0/1
+    matmuls, because Mosaic cannot merge two axes into the lane axis."""
+    r, s = u.shape[1], m.shape[1]
+    return mxu_dot(u, _spread(r, s, True)) * mxu_dot(m, _spread(r, s, False))
 
 
 def _kernel(*refs, n_contract: int, acc_dtype):
@@ -55,21 +67,30 @@ def _kernel(*refs, n_contract: int, acc_dtype):
     def _zero():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # chained Kronecker product over the contraction tiles:
-    # w rows follow the C-order flattening of (c_1..c_k), columns the
-    # C-order flattening of (r_1..r_k) — both match the x/out reshapes
-    w = m_refs[0][...].astype(acc_dtype)  # (b1, R1)
-    for f in m_refs[1:]:
-        ft = f[...].astype(acc_dtype)  # (bd, Rd)
-        pc, pr = w.shape
-        w = (w[:, None, :, None] * ft[None, :, None, :]).reshape(
-            pc * ft.shape[0], pr * ft.shape[1]
-        )
-    bi = x_ref.shape[0]
-    xm = x_ref[...].reshape(bi, -1)
-    o_ref[...] += jax.lax.dot_general(
-        xm, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
+    # Per index of the leading contraction axes c_1..c_{k-2}, the
+    # (bi, c_{k-1}, c_k) slab meets A_k on the MXU, T(i, c_{k-1}, r_k);
+    # then a batched MXU contraction over c_{k-1} against the Kronecker
+    # weight W[c_{k-1}, (r_1..r_{k-1})] = prod_d A_d(c_d, r_d) gives the
+    # (bi, R_k, R_1..R_{k-1}) block of the output.
+    bi, c_sub, c_min = x_ref.shape[0], x_ref.shape[-2], x_ref.shape[-1]
+    lead_refs, m_sub, m_min = m_refs[:-2], m_refs[-2], m_refs[-1]
+
+    def body(idx, acc):
+        slab = x_ref[(slice(None),) + tuple(idx)]
+        t = mxu_dot(slab.reshape(bi * c_sub, c_min), m_min[...])
+        t = t.reshape(bi, c_sub, m_min.shape[1])
+        u = None
+        for m, a in zip(lead_refs, idx):
+            r = row(m, a, acc_dtype)
+            u = r if u is None else _kron_cols(u, r)
+        w = m_sub[...].astype(acc_dtype)
+        if u is not None:
+            w = _kron_cols(u, w)
+        wb = jnp.broadcast_to(w[None], (bi,) + w.shape)
+        return acc + mxu_dot(t, wb, contract=((1,), (1,)), batch=((0,), (0,)))
+
+    o_ref[...] += fori_leading(
+        x_ref.shape[1:-2], body, jnp.zeros(o_ref.shape, acc_dtype)
     )
 
 
@@ -87,6 +108,7 @@ def multi_ttm_keep_pallas(
     Pre-padded tensor-mode extents required (the R_d are never padded);
     returns the flattened ``(I, prod R_d)`` in ``acc_dtype``."""
     nc = x.ndim - 1
+    assert nc >= 2, "the kernel contracts at least two modes"
     assert len(matrices) == nc and len(block_contract) == nc
     i_sz = x.shape[0]
     ranks = tuple(m.shape[1] for m in matrices)
@@ -94,9 +116,7 @@ def multi_ttm_keep_pallas(
         assert m.shape[0] == x.shape[1 + d]
         assert x.shape[1 + d] % block_contract[d] == 0
     assert i_sz % block_i == 0
-    prod_r = 1
-    for r in ranks:
-        prod_r *= r
+    r_lead = math.prod(ranks[:-1])
 
     grid = (i_sz // block_i,) + tuple(
         x.shape[1 + d] // block_contract[d] for d in range(nc)
@@ -111,7 +131,7 @@ def multi_ttm_keep_pallas(
         return m_map
 
     def o_map(i, *cs):
-        return (i, 0)
+        return (i, 0, 0)
 
     in_specs = [
         pl.BlockSpec((block_i,) + tuple(block_contract), x_map)
@@ -120,16 +140,17 @@ def multi_ttm_keep_pallas(
         for d in range(nc)
     ]
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
-    kwargs = {}
-    cp = _compiler_params(nc)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
-    return pl.pallas_call(
+    # the kernel's block is (i, r_k, r_1..r_{k-1}); restoring C order
+    # moves only the small (I, prod R_d) output
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((block_i, prod_r), o_map),
-        out_shape=jax.ShapeDtypeStruct((i_sz, prod_r), acc_dtype),
+        out_specs=pl.BlockSpec((block_i, ranks[-1], r_lead), o_map),
+        out_shape=jax.ShapeDtypeStruct(
+            (i_sz, ranks[-1], r_lead), acc_dtype
+        ),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(1, nc),
     )(x, *matrices)
+    return jnp.swapaxes(out, 1, 2).reshape(i_sz, r_lead * ranks[-1])

@@ -17,11 +17,12 @@ runs a fresh full MTTKRP — see :mod:`repro.engine.sweep` for the schedule).
 This kernel computes the (B^(0), P) pair as a two-output ``pallas_call``
 with the exact output-stationary layout of :mod:`repro.kernels.mttkrpn`:
 grid ``(r, i, c_1..c_{N-1})`` with the contraction tiles innermost, the
-X tile loaded ONCE per grid step and consumed by both accumulators —
-B^(0) against the chained Khatri-Rao weight block (MXU), P against the
-last factor tile alone (MXU). Both outputs stay VMEM-resident across
-their contraction revisits (B^(0) across all contraction steps; P across
-the innermost ``c_{N-1}`` sweep, the only grid dim its index map drops).
+X tile loaded ONCE per grid step and contracted ONCE on the MXU against
+the last factor tile: that product is P's update, and its reduction
+against the other factors' Khatri-Rao weights (VPU) is B^(0)'s. Both
+outputs stay VMEM-resident across their contraction revisits (B^(0)
+across all contraction steps; P across the innermost ``c_{N-1}`` sweep,
+the only grid dim its index map drops).
 """
 
 from __future__ import annotations
@@ -33,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .mttkrpn import _compiler_params
+from .common import compiler_params
+from .mttkrpn import krp_contract
 
 
 def _fused_pair_kernel(*refs, n_contract: int, acc_dtype):
@@ -56,26 +58,9 @@ def _fused_pair_kernel(*refs, n_contract: int, acc_dtype):
     def _zero_p():
         p_ref[...] = jnp.zeros_like(p_ref)
 
-    br = f_refs[0].shape[1]
-    bi = x_ref.shape[0]
-    # chained outer product over the contraction tile dims (Khatri-Rao)
-    w = f_refs[0][...].astype(acc_dtype)  # (b1, br)
-    for f in f_refs[1:]:
-        ft = f[...].astype(acc_dtype)  # (bd, br)
-        w = (w[:, None, :] * ft[None, :, :]).reshape(-1, br)
-    xm = x_ref[...].reshape(bi, -1)
-    b0_ref[...] += jax.lax.dot_general(
-        xm, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
-    # same X tile, second consumer: contract only the last axis with A_{N-1}
-    bc_last = f_refs[-1].shape[0]
-    xr = x_ref[...].reshape(-1, bc_last)  # (bi*prod(bc[:-1]), bc_last)
-    p = jax.lax.dot_general(
-        xr, f_refs[-1][...], dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
-    p_ref[...] += p.reshape(p_ref.shape)
+    # one MXU pass per slab feeds both outputs: P accumulates T = X x A_{N-1}
+    # and B^(0) its Khatri-Rao-weighted reduction
+    b0_ref[...] += krp_contract(x_ref, f_refs, acc_dtype, p_ref=p_ref)
 
 
 def mttkrp_fused_pair_pallas(
@@ -133,10 +118,6 @@ def mttkrp_fused_pair_pallas(
     kernel = functools.partial(
         _fused_pair_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    kwargs = {}
-    cp = _compiler_params(nc)
-    if cp is not None and not interpret:
-        kwargs["compiler_params"] = cp
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -150,7 +131,7 @@ def mttkrp_fused_pair_pallas(
             jax.ShapeDtypeStruct(p_shape, acc_dtype),
         ),
         interpret=interpret,
-        **kwargs,
+        compiler_params=compiler_params(2, nc),
     )(x, *factors)
 
 

@@ -24,12 +24,14 @@ an :class:`~repro.engine.context.ExecutionContext` with
 ``compilation_cache=<dir>`` makes the server call
 ``ensure_compilation_cache()`` before its first flush, so a second
 server process serving the same buckets reloads every compiled program
-from disk (``benchmarks/serve.py`` measures the cold/warm split).
+from disk.  The CLI keeps that cache in the checkout's ``.cache/jax``
+(``CHECKOUT_COMPILATION_CACHE``) unless ``JAX_COMPILATION_CACHE_DIR``
+names another directory.
 
 CLI demo (synthetic workload, prints req/s)::
 
     PYTHONPATH=src python -m repro.launch.serve \
-        --requests 16 --shape 12x10x8 --rank 4 --cache-dir /tmp/srv
+        --requests 16 --shape 12x10x8 --rank 4
 """
 
 from __future__ import annotations
@@ -43,7 +45,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
-from ..engine.context import ExecutionContext
+from ..engine.context import CHECKOUT_COMPILATION_CACHE, ExecutionContext
 from ..engine.plan import Memory
 from ..observe import trace as _otrace
 
@@ -288,8 +290,9 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--tol", type=float, default=1e-4)
     ap.add_argument("--pad-to", type=int, default=DEFAULT_PAD_TO)
     ap.add_argument(
-        "--cache-dir", default=None,
-        help="persistent XLA compilation cache directory (warm starts)",
+        "--cache-dir", default=CHECKOUT_COMPILATION_CACHE,
+        help="persistent XLA compilation cache directory (warm starts); "
+        "JAX_COMPILATION_CACHE_DIR, when set, takes its place",
     )
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)

@@ -269,10 +269,12 @@ def check_cp_sweep_matches_sequential():
 
     dims, rank = (16, 16, 24), 4
     x, _ = random_low_rank_tensor(jax.random.PRNGKey(30), dims, rank)
+    # 12 sweeps: from this seed's initial factors the sequential driver
+    # is still climbing at sweep 8 (fit 0.94) and converges by sweep 12
     par = cp_als_parallel(
-        x, rank, n_iters=8, key=jax.random.PRNGKey(31), grid=(2, 2, 2)
+        x, rank, n_iters=12, key=jax.random.PRNGKey(31), grid=(2, 2, 2)
     )
-    seq = cp_als(x, rank, n_iters=8, key=jax.random.PRNGKey(31))
+    seq = cp_als(x, rank, n_iters=12, key=jax.random.PRNGKey(31))
     for fp, fs_ in zip(par.fits, seq.fits):
         assert abs(fp - fs_) < 1e-3, (fp, fs_)
     for k in range(3):
@@ -404,7 +406,9 @@ def check_cp_auto_grid_driver():
     dims, rank = (16, 16, 16), 4
     choice = choose_cp_grid(dims, rank, len(jax.devices()))
     assert choice.procs == 8 and choice.grid == (2, 2, 2), choice
-    x, _ = random_low_rank_tensor(jax.random.PRNGKey(34), dims, rank)
+    # data key 41: the tensor from key 34 traps ALS from this init in a
+    # swamp (the sequential driver too stalls at fit 0.77 for 25 sweeps)
+    x, _ = random_low_rank_tensor(jax.random.PRNGKey(41), dims, rank)
     res = cp_als(x, rank, n_iters=25, key=jax.random.PRNGKey(2),
                  distributed=True)
     assert res.final_fit > 0.999, res.fits
@@ -466,8 +470,10 @@ def check_context_roundtrip_reproduces_sweep():
         before = registry().counter(PALLAS_DISPATCHES)
         lowered = sweep.lower(xs, f_sh, blocks, grams, normx)
         dispatches = registry().counter(PALLAS_DISPATCHES) - before
-        text = lowered.compile().as_text()
-        ring = parse_collectives(text).ring_bytes
+        ring = parse_collectives(lowered.compile().as_text()).ring_bytes
+        # the lowered program without source locations: the compiled text
+        # also lists every call site's stack frames, which differ per call
+        text = lowered.as_text()
         return (ring, dispatches, text) if want_text else (ring, dispatches)
 
     bytes1, disp1 = measure(ctx)

@@ -134,14 +134,14 @@ def test_roundtrip_full_fields(tuned_env):
         (8, 6, 5), 3, dtype=jnp.float32,
         backend="auto", memory=Memory.tpu_vmem(itemsize=4),
         out_dtype="float32", interpret=True,
-        grid=None, distributed=True, procs=4, check_rep=False,
+        grid=None, distributed=True, procs=4, overlap="ring",
     )
     back = ExecutionContext.from_json(ctx.to_json())
     assert back == ctx and hash(back) == hash(ctx)
     # field-level: Memory, grid, dtype policy, decisions all survive
     assert back.memory == ctx.memory
     assert back.distribution.grid == ctx.distribution.grid
-    assert back.distribution.check_rep is False
+    assert back.distribution.overlap == "ring"
     assert back.out_dtype == "float32"
     assert back.problem == ProblemSpec((8, 6, 5), 3, "float32")
     assert back.decisions == ctx.decisions

@@ -188,7 +188,10 @@ def test_fused_working_set_exceeds_single_mode():
 
 def test_cp_als_fused_matches_per_mode():
     dims, rank = (16, 14, 12), 4
-    x, _ = random_low_rank_tensor(jax.random.PRNGKey(6), dims, rank)
+    # data key 17: under JAX's partitionable threefry stream the tensor
+    # from key 6 traps ALS from this init in a swamp (fit 0.74 after 60
+    # sweeps, in float64 numpy too)
+    x, _ = random_low_rank_tensor(jax.random.PRNGKey(17), dims, rank)
     key = jax.random.PRNGKey(7)
     per = cp_als(x, rank, n_iters=10, key=key, sweep="per_mode")
     fus = cp_als(x, rank, n_iters=10, key=key, sweep="fused")
