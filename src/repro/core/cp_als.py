@@ -175,6 +175,24 @@ def cp_als(
             x, rank, n_iters, key=key, init_factors=init_factors,
             ctx=ctx, tol=tol,
         )
+    from ..observe import trace as _otrace
+
+    schedule = sweep if sweep is not None else (
+        "dimtree" if use_dimension_tree else "per_mode"
+    )
+    with _otrace.annotated("repro.cp_als"):
+        return _cp_als_local(
+            x, rank, n_iters, key, init_factors, mttkrp_fn, tol, schedule,
+            ctx,
+        )
+
+
+def _cp_als_local(
+    x, rank, n_iters, key, init_factors, mttkrp_fn, tol, schedule, ctx,
+) -> CPResult:
+    """The single-device ALS loop of :func:`cp_als` (options checked)."""
+    from ..observe import trace as _otrace
+
     n = x.ndim
     if init_factors is not None:
         factors = [jnp.asarray(f) for f in init_factors]
@@ -189,24 +207,25 @@ def cp_als(
 
     def update(mode: int, b: jax.Array) -> jax.Array:
         nonlocal weights
-        gamma = _hadamard_except(grams, mode)
-        # solve A_n Γ = B  (Γ is PSD; ridge for rank-deficiency safety)
-        solve_dtype = jnp.float32 if x.dtype != jnp.float64 else x.dtype
-        gamma32 = gamma.astype(solve_dtype)
-        # ridge scaled to f32 conditioning; essential when rank exceeds the
-        # true tensor rank (Γ singular)
-        ridge = 1e-5 * jnp.trace(gamma32) / rank + 1e-12
-        a_new = jnp.linalg.solve(
-            gamma32 + ridge * jnp.eye(rank, dtype=solve_dtype),
-            b.astype(solve_dtype).T,
-        ).T.astype(x.dtype)
-        # column normalization
-        lam = jnp.maximum(jnp.linalg.norm(a_new, axis=0), 1e-30)
-        a_new = a_new / lam
-        weights = lam.astype(x.dtype)
-        grams[mode] = a_new.T @ a_new
-        state.update(b_last=b, a_last=a_new * weights, g_last=mode)
-        return a_new
+        with _otrace.annotated("repro.cp_als.update"):
+            gamma = _hadamard_except(grams, mode)
+            # solve A_n Γ = B  (Γ is PSD; ridge for rank-deficiency safety)
+            solve_dtype = jnp.float32 if x.dtype != jnp.float64 else x.dtype
+            gamma32 = gamma.astype(solve_dtype)
+            # ridge scaled to f32 conditioning; essential when rank exceeds
+            # the true tensor rank (Γ singular)
+            ridge = 1e-5 * jnp.trace(gamma32) / rank + 1e-12
+            a_new = jnp.linalg.solve(
+                gamma32 + ridge * jnp.eye(rank, dtype=solve_dtype),
+                b.astype(solve_dtype).T,
+            ).T.astype(x.dtype)
+            # column normalization
+            lam = jnp.maximum(jnp.linalg.norm(a_new, axis=0), 1e-30)
+            a_new = a_new / lam
+            weights = lam.astype(x.dtype)
+            grams[mode] = a_new.T @ a_new
+            state.update(b_last=b, a_last=a_new * weights, g_last=mode)
+            return a_new
 
     from ..engine import execute as engine_execute
     from ..engine.sweep import fused_als_sweep
@@ -216,9 +235,6 @@ def cp_als(
         def mttkrp_fn(t, fs, mode):
             return engine_execute.mttkrp(t, fs, mode, ctx=ctx)
 
-    schedule = sweep if sweep is not None else (
-        "dimtree" if use_dimension_tree else "per_mode"
-    )
     if schedule == "auto":
         from ..tune.search import _is_concrete, resolve_sweep, tune_sweep
 
@@ -231,36 +247,40 @@ def cp_als(
             x.shape, rank, x.dtype, ctx.memory, cache=ctx.plan_cache()
         ).variant
 
-    from ..observe import trace as _otrace
-
     for it in range(n_iters):
-        if schedule == "dimtree":
-            dimtree_als_sweep(x, factors, update, ctx=ctx)
-        elif schedule == "fused":
-            fused_als_sweep(x, factors, update, ctx=ctx)
-        else:
-            for mode in range(n):
-                factors[mode] = update(mode, mttkrp_fn(x, factors, mode))
-        gram_full = _hadamard_except(grams, -1) * jnp.outer(weights, weights)
-        b_last, a_last = state["b_last"], state["a_last"]
-        fit = float(_fit(normx, b_last, a_last, gram_full))
-        fits.append(fit)
-        delta = abs(fits[-1] - fits[-2]) if it > 0 else None
-        converged = bool(tol and it > 0 and delta < tol)
-        # float(_fit) above forces concreteness, so this loop never runs
-        # under a jax trace — no tracer guard needed here.
-        if _otrace.should_record(ctx.observe):
-            _otrace.record_event(
-                "cp_als_iter",
-                shape=list(x.shape),
-                rank=int(rank),
-                schedule=schedule,
-                it=it,
-                fit=fit,
-                fit_delta=delta,
-                weights=[float(w) for w in weights],
-                converged=converged,
-            )
+        t_sweep = _otrace.now_ns()
+        with _otrace.annotated("repro.cp_als.sweep", step=it):
+            if schedule == "dimtree":
+                dimtree_als_sweep(x, factors, update, ctx=ctx)
+            elif schedule == "fused":
+                fused_als_sweep(x, factors, update, ctx=ctx)
+            else:
+                for mode in range(n):
+                    factors[mode] = update(mode, mttkrp_fn(x, factors, mode))
+            with _otrace.annotated("repro.cp_als.fit"):
+                gram_full = _hadamard_except(grams, -1) * jnp.outer(
+                    weights, weights
+                )
+                b_last, a_last = state["b_last"], state["a_last"]
+                fit = float(_fit(normx, b_last, a_last, gram_full))
+            fits.append(fit)
+            delta = abs(fits[-1] - fits[-2]) if it > 0 else None
+            converged = bool(tol and it > 0 and delta < tol)
+            # float(_fit) above forces concreteness, so this loop never
+            # runs under a jax trace — no tracer guard needed here.
+            if _otrace.should_record(ctx.observe):
+                _otrace.record_event(
+                    "cp_als_iter",
+                    start_ns=t_sweep,
+                    shape=list(x.shape),
+                    rank=int(rank),
+                    schedule=schedule,
+                    it=it,
+                    fit=fit,
+                    fit_delta=delta,
+                    weights=weights,
+                    converged=converged,
+                )
         if converged:
             break
     # Kruskal form: factors stay column-normalized, λ is returned ONLY in

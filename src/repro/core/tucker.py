@@ -164,13 +164,23 @@ def tucker_hooi(
         return tucker_hooi_parallel(
             x, ranks, n_iters, ctx=ctx, init_factors=init_factors, tol=tol
         )
+    from ..observe import trace as _otrace
+
+    with _otrace.annotated("repro.tucker_hooi"):
+        return _tucker_hooi_local(x, ranks, n_iters, init_factors, tol, ctx)
+
+
+def _tucker_hooi_local(x, ranks, n_iters, init_factors, tol, ctx):
+    """The single-device HOOI loop of :func:`tucker_hooi` (checked)."""
     from ..engine import execute as engine_execute
+    from ..observe import trace as _otrace
 
     n = x.ndim
     if init_factors is not None:
         factors = [jnp.asarray(f) for f in init_factors]
     else:
-        factors = hosvd_init(x, ranks)
+        with _otrace.annotated("repro.tucker.hosvd_init"):
+            factors = hosvd_init(x, ranks)
     normx = frob_norm(x)
     fits: list[float] = []
     if n_iters < 1:  # HOSVD only: just project onto the initial factors
@@ -180,32 +190,41 @@ def tucker_hooi(
             float(1.0 - jnp.sqrt(err_sq) / jnp.maximum(normx, 1e-30))
         )
         return TuckerResult(core, factors, fits)
-    from ..observe import trace as _otrace
 
     for it in range(n_iters):
-        for k in range(n):
-            y = engine_execute.multi_ttm(x, factors, keep=k, ctx=ctx)
-            ym = _unfold_rows(y, k)
-            factors[k] = _leading_eigvecs(ym @ ym.T, ranks[k]).astype(x.dtype)
-        # the core falls out of the last mode update: contract mode N-1
-        # of its Y with the fresh A_{N-1} (no extra pass over X)
-        core = ttm(y, factors[n - 1], n - 1)
-        err_sq = jnp.maximum(normx**2 - frob_norm(core) ** 2, 0.0)
-        fit = float(1.0 - jnp.sqrt(err_sq) / jnp.maximum(normx, 1e-30))
-        fits.append(fit)
-        delta = abs(fits[-1] - fits[-2]) if it > 0 else None
-        converged = bool(tol and it > 0 and delta < tol)
-        # float(...) above forces concreteness: never inside a jax trace.
-        if _otrace.should_record(ctx.observe):
-            _otrace.record_event(
-                "tucker_iter",
-                shape=list(x.shape),
-                ranks=list(ranks),
-                it=it,
-                fit=fit,
-                fit_delta=delta,
-                converged=converged,
-            )
+        t_sweep = _otrace.now_ns()
+        with _otrace.annotated("repro.tucker.sweep", step=it):
+            for k in range(n):
+                y = engine_execute.multi_ttm(x, factors, keep=k, ctx=ctx)
+                with _otrace.annotated("repro.tucker.eigh"):
+                    ym = _unfold_rows(y, k)
+                    factors[k] = _leading_eigvecs(
+                        ym @ ym.T, ranks[k]
+                    ).astype(x.dtype)
+            with _otrace.annotated("repro.tucker.fit"):
+                # the core falls out of the last mode update: contract
+                # mode N-1 of its Y with the fresh A_{N-1} (no extra pass
+                # over X)
+                core = ttm(y, factors[n - 1], n - 1)
+                err_sq = jnp.maximum(normx**2 - frob_norm(core) ** 2, 0.0)
+                fit = float(
+                    1.0 - jnp.sqrt(err_sq) / jnp.maximum(normx, 1e-30)
+                )
+            fits.append(fit)
+            delta = abs(fits[-1] - fits[-2]) if it > 0 else None
+            converged = bool(tol and it > 0 and delta < tol)
+            # float(...) above forces concreteness: never inside a trace.
+            if _otrace.should_record(ctx.observe):
+                _otrace.record_event(
+                    "tucker_iter",
+                    start_ns=t_sweep,
+                    shape=list(x.shape),
+                    ranks=list(ranks),
+                    it=it,
+                    fit=fit,
+                    fit_delta=delta,
+                    converged=converged,
+                )
         if converged:
             break
     return TuckerResult(core, factors, fits)

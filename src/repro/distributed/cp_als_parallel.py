@@ -410,7 +410,6 @@ def cp_als_parallel(
         # Driver level (outside the shard_map program): lower the sweep
         # once more and walk its HLO for the actual collective bytes, so
         # the trace carries a measured/modeled pair per the §V-C3 model.
-        from ..observe.metrics import SWEEP_COLLECTIVE_BYTES, registry
         from .grid_select import stationary_sweep_words
         from .hlo import parse_collectives
 
@@ -426,7 +425,6 @@ def cp_als_parallel(
             int(2 * (nproc - 1) / nproc * itemsize)
             if (compute_fit or tol > 0) else 0
         )
-        registry().observe(SWEEP_COLLECTIVE_BYTES, float(summ.ring_bytes))
         _otrace.record_event(
             "cp_sweep_collectives",
             shape=list(x.shape),

@@ -373,7 +373,6 @@ def tucker_hooi_parallel(
     if _otrace.should_record(ctx.observe):
         # Driver level: lower the sweep once more and walk its HLO for the
         # actual collective bytes next to the Multi-TTM sweep model.
-        from ..observe.metrics import SWEEP_COLLECTIVE_BYTES, registry
         from .grid_select import multi_ttm_sweep_words
         from .hlo import parse_collectives
 
@@ -382,7 +381,6 @@ def tucker_hooi_parallel(
         summ = parse_collectives(text)
         itemsize = int(x.dtype.itemsize)
         modeled = int(multi_ttm_sweep_words(x.shape, ranks, grid))
-        registry().observe(SWEEP_COLLECTIVE_BYTES, float(summ.ring_bytes))
         _otrace.record_event(
             "tucker_sweep_collectives",
             shape=list(x.shape),

@@ -179,6 +179,17 @@ def cp_als_batched(
             "distributed contexts run repro.cp_als per tensor (the "
             "stationary sweep owns the collectives)"
         )
+    from ..observe import trace as _otrace
+
+    with _otrace.annotated("repro.cp_als_batched"):
+        return _cp_als_batched(x, rank, n_iters, key, init_factors, tol, ctx)
+
+
+def _cp_als_batched(x, rank, n_iters, key, init_factors, tol, ctx):
+    """The batched ALS loop of :func:`cp_als_batched` (checked)."""
+    from ..observe import trace as _otrace
+    from . import execute as engine_execute
+
     batch, dims = x.shape[0], x.shape[1:]
     n = len(dims)
     if init_factors is not None:
@@ -200,9 +211,6 @@ def cp_als_batched(
             ])
         ]
 
-    from ..observe import trace as _otrace
-    from . import execute as engine_execute
-
     normx = jnp.sqrt(
         jnp.sum(jnp.square(x.astype(jnp.float32)), axis=tuple(range(1, n + 1)))
     )
@@ -219,60 +227,66 @@ def cp_als_batched(
     def update(mode: int, b: jax.Array, active: jax.Array):
         """One batched mode update, frozen where ``active`` is False."""
         nonlocal weights
-        gamma = _batched_hadamard_except(grams, mode).astype(solve_dtype)
-        ridge = (
-            1e-5 * jnp.trace(gamma, axis1=1, axis2=2) / rank + 1e-12
-        )[:, None, None]
-        a_new = jnp.linalg.solve(
-            gamma + ridge * eye,
-            jnp.swapaxes(b.astype(solve_dtype), 1, 2),
-        )
-        a_new = jnp.swapaxes(a_new, 1, 2).astype(x.dtype)
-        lam = jnp.maximum(jnp.linalg.norm(a_new, axis=1), 1e-30)
-        a_new = a_new / lam[:, None, :]
-        # the convergence mask: frozen elements keep their old factors,
-        # weights, and Grams bit-for-bit
-        a_new = jnp.where(active[:, None, None], a_new, factors[mode])
-        weights = jnp.where(
-            active[:, None], lam.astype(x.dtype), weights
-        )
-        grams[mode] = jnp.einsum("bir,bis->brs", a_new, a_new)
-        state.update(
-            b_last=b, a_last=a_new * weights[:, None, :], mode=mode
-        )
-        return a_new
+        with _otrace.annotated("repro.cp_als_batched.update"):
+            gamma = _batched_hadamard_except(grams, mode).astype(solve_dtype)
+            ridge = (
+                1e-5 * jnp.trace(gamma, axis1=1, axis2=2) / rank + 1e-12
+            )[:, None, None]
+            a_new = jnp.linalg.solve(
+                gamma + ridge * eye,
+                jnp.swapaxes(b.astype(solve_dtype), 1, 2),
+            )
+            a_new = jnp.swapaxes(a_new, 1, 2).astype(x.dtype)
+            lam = jnp.maximum(jnp.linalg.norm(a_new, axis=1), 1e-30)
+            a_new = a_new / lam[:, None, :]
+            # the convergence mask: frozen elements keep their old
+            # factors, weights, and Grams bit-for-bit
+            a_new = jnp.where(active[:, None, None], a_new, factors[mode])
+            weights = jnp.where(
+                active[:, None], lam.astype(x.dtype), weights
+            )
+            grams[mode] = jnp.einsum("bir,bis->brs", a_new, a_new)
+            state.update(
+                b_last=b, a_last=a_new * weights[:, None, :], mode=mode
+            )
+            return a_new
 
     for it in range(n_iters):
-        active = ~converged
-        for mode in range(n):
-            # ONE batched engine dispatch for all B elements
-            b = engine_execute.mttkrp(x, factors, mode, ctx=ctx)
-            factors[mode] = update(mode, b, active)
-        gram_full = _batched_hadamard_except(grams, -1) * jnp.einsum(
-            "br,bs->brs", weights, weights
-        )
-        new_fits = _batched_fit(
-            normx, state["b_last"], state["a_last"], gram_full
-        )
-        new_fits = jnp.where(active, new_fits, fits)
-        delta = jnp.abs(new_fits - fits)
-        fits = new_fits
-        fit_history.append(fits)
-        iters_run = iters_run + active.astype(jnp.int32)
-        if tol and it > 0:
-            converged = converged | (active & (delta < tol))
-        if _otrace.should_record(ctx.observe):
-            _otrace.record_event(
-                "cp_als_batched_iter",
-                batch=int(batch),
-                shape=list(dims),
-                rank=int(rank),
-                it=it,
-                fits=[float(f) for f in fits],
-                converged=[bool(c) for c in converged],
-            )
-        if tol and bool(converged.all()):
-            break
+        t_sweep = _otrace.now_ns()
+        with _otrace.annotated("repro.cp_als_batched.sweep", step=it):
+            active = ~converged
+            for mode in range(n):
+                # ONE batched engine dispatch for all B elements
+                b = engine_execute.mttkrp(x, factors, mode, ctx=ctx)
+                factors[mode] = update(mode, b, active)
+            with _otrace.annotated("repro.cp_als_batched.fit"):
+                gram_full = _batched_hadamard_except(grams, -1) * jnp.einsum(
+                    "br,bs->brs", weights, weights
+                )
+                new_fits = _batched_fit(
+                    normx, state["b_last"], state["a_last"], gram_full
+                )
+                new_fits = jnp.where(active, new_fits, fits)
+                delta = jnp.abs(new_fits - fits)
+                fits = new_fits
+                fit_history.append(fits)
+                iters_run = iters_run + active.astype(jnp.int32)
+                if tol and it > 0:
+                    converged = converged | (active & (delta < tol))
+            if _otrace.should_record(ctx.observe):
+                # arrays, read when the trace is: recording never syncs
+                _otrace.record_event(
+                    "cp_als_batched_iter",
+                    start_ns=t_sweep,
+                    batch=int(batch),
+                    shape=list(dims),
+                    rank=int(rank),
+                    it=it,
+                    fits=fits,
+                    converged=converged,
+                )
+            if tol and bool(converged.all()):
+                break
     return BatchedCPResult(
         factors, weights, fits, iters_run, converged, fit_history
     )
@@ -413,8 +427,8 @@ def tucker_hooi_batched(
                 shape=list(dims),
                 ranks=list(ranks),
                 it=it,
-                fits=[float(f) for f in fits],
-                converged=[bool(c) for c in converged],
+                fits=fits,
+                converged=converged,
             )
         if tol and bool(converged.all()):
             break

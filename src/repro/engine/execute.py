@@ -42,7 +42,6 @@ package, so importing kernels first must not re-enter ``engine``.
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import replace
 from typing import Sequence
 
@@ -174,7 +173,7 @@ def mttkrp(
             x, factors, mode, ctx, plan, block, out_dtype, kernel_variant,
         )
     span: dict = {}
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated(f"repro.mttkrp.mode{mode}"):
         out = _mttkrp_impl(
             x, factors, mode, ctx, plan, block, out_dtype, kernel_variant,
@@ -191,10 +190,11 @@ def mttkrp(
 def _record_mttkrp_span(
     kind: str, ctx, shape, rank, mode, itemsize, span, t0, **extra
 ) -> None:
-    """Emit one MTTKRP-shaped dispatch event: resolved backend/plan (as
-    filled in by the impl), the Eq-10 modeled words for the plan (the
-    model plan against the resolver's default memory when the backend
-    carried none), and the Thm-4.1 lower bound, clamped at 0."""
+    """Emit one MTTKRP-shaped dispatch event from ``t0`` (ns, the
+    profiler's clock) to now: resolved backend/plan (as filled in by the
+    impl), the Eq-10 modeled words for the plan (the model plan against
+    the resolver's default memory when the backend carried none), and the
+    Thm-4.1 lower bound, clamped at 0."""
     from ..core.bounds import seq_lb_memory
 
     mem = ctx.memory or Memory.tpu_vmem(itemsize=itemsize)
@@ -217,11 +217,10 @@ def _record_mttkrp_span(
         ),
         "memory_words": mem.budget_words,
         "itemsize": int(itemsize),
-        "wall_time_us": (time.perf_counter() - t0) * 1e6,
         **_dtype_policy(ctx),
         **extra,
     }
-    _otrace.record_event(kind, **event)
+    _otrace.record_event(kind, start_ns=t0, **event)
 
 
 def _mttkrp_impl(
@@ -235,23 +234,24 @@ def _mttkrp_impl(
         out_dtype = ctx.out_dtype
     x, factors, out_dtype, mixed = _cast_compute(ctx, x, factors, out_dtype)
     if backend == "auto":
-        rank = next(
-            f.shape[1] for k, f in enumerate(factors) if k != mode
-        )
-        decision = ctx.decision_for(x.shape, rank, mode, x.dtype)
-        if decision is None:
-            # lazy import: engine <-> tune layer cycle
-            from ..tune.search import _is_concrete, resolve, tune_mttkrp
+        with _otrace.annotated("repro.engine.resolve"):
+            rank = next(
+                f.shape[1] for k, f in enumerate(factors) if k != mode
+            )
+            decision = ctx.decision_for(x.shape, rank, mode, x.dtype)
+            if decision is None:
+                # lazy import: engine <-> tune layer cycle
+                from ..tune.search import _is_concrete, resolve, tune_mttkrp
 
-            if ctx.tune and _is_concrete(x):
-                tune_mttkrp(
-                    x, factors, mode, memory=memory, interpret=interpret,
+                if ctx.tune and _is_concrete(x):
+                    tune_mttkrp(
+                        x, factors, mode, memory=memory,
+                        interpret=interpret, cache=ctx.plan_cache(),
+                    )
+                decision = resolve(
+                    _mode_first(x.shape, mode), rank, mode, x.dtype, memory,
                     cache=ctx.plan_cache(),
                 )
-            decision = resolve(
-                _mode_first(x.shape, mode), rank, mode, x.dtype, memory,
-                cache=ctx.plan_cache(),
-            )
         backend = decision.backend
         plan = plan if plan is not None else decision.plan
         block = block if block is not None else decision.block
@@ -285,10 +285,11 @@ def _mttkrp_impl(
         if mixed:
             # dtype-aware planning: same physical budget, narrower items
             memory = memory.with_itemsize(x.dtype.itemsize)
-        plan = choose_blocks(
-            _mode_first(x.shape, mode), rank, x.dtype.itemsize,
-            memory=memory,
-        )
+        with _otrace.annotated("repro.engine.resolve"):
+            plan = choose_blocks(
+                _mode_first(x.shape, mode), rank, x.dtype.itemsize,
+                memory=memory,
+            )
     if _span is not None:
         _span["plan"] = plan
         _span["variant"] = kernel_variant
@@ -369,14 +370,15 @@ def _mttkrp_batched(
     )
     backend = ctx.backend
     if backend == "auto":
-        decision = ctx.decision_for(elem_shape, rank, mode, x.dtype)
-        if decision is None:
-            from ..tune.search import resolve  # lazy: engine <-> tune
+        with _otrace.annotated("repro.engine.resolve"):
+            decision = ctx.decision_for(elem_shape, rank, mode, x.dtype)
+            if decision is None:
+                from ..tune.search import resolve  # lazy: engine <-> tune
 
-            decision = resolve(
-                _mode_first(elem_shape, mode), rank, mode, x.dtype,
-                ctx.memory, cache=ctx.plan_cache(),
-            )
+                decision = resolve(
+                    _mode_first(elem_shape, mode), rank, mode, x.dtype,
+                    ctx.memory, cache=ctx.plan_cache(),
+                )
         backend = decision.backend
         plan = plan if plan is not None else decision.plan
         block = block if block is not None else decision.block
@@ -392,7 +394,7 @@ def _mttkrp_batched(
     vmapped = jax.vmap(one, in_axes=(0, *axes))
     if not _otrace.should_record(ctx.observe, x, *factors):
         return vmapped(x, *factors)
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated(f"repro.mttkrp.batched.mode{mode}"):
         out = vmapped(x, *factors)
     span = {"backend": backend, "plan": plan}
@@ -451,7 +453,7 @@ def contract_partial(
             node, factors, modes, drop, has_rank, ctx, plan
         )
     span: dict = {}
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated("repro.contract_partial"):
         out = _contract_partial_impl(
             node, factors, modes, drop, has_rank, ctx, plan, _span=span,
@@ -490,19 +492,21 @@ def _contract_partial_impl(
         # lazy import: engine <-> tune layer cycle
         from ..tune.search import _is_concrete, resolve, tune_partial
 
-        if ctx.tune and _is_concrete(node):
-            tune_partial(
-                node, factors, modes, drop, has_rank, memory=memory,
-                interpret=interpret, cache=ctx.plan_cache(),
+        with _otrace.annotated("repro.engine.resolve"):
+            if ctx.tune and _is_concrete(node):
+                tune_partial(
+                    node, factors, modes, drop, has_rank, memory=memory,
+                    interpret=interpret, cache=ctx.plan_cache(),
+                )
+            pos0 = {m: i for i, m in enumerate(modes)}
+            canon_shape = (
+                math.prod(node.shape[pos0[m]] for m in keep) if keep else 1,
+            ) + tuple(node.shape[pos0[m]] for m in drop)
+            resolved = resolve(
+                canon_shape, factors[drop[0]].shape[1], 0, node.dtype,
+                memory, kind="partial", x_has_rank=has_rank,
+                cache=ctx.plan_cache(),
             )
-        pos0 = {m: i for i, m in enumerate(modes)}
-        canon_shape = (
-            math.prod(node.shape[pos0[m]] for m in keep) if keep else 1,
-        ) + tuple(node.shape[pos0[m]] for m in drop)
-        resolved = resolve(
-            canon_shape, factors[drop[0]].shape[1], 0, node.dtype, memory,
-            kind="partial", x_has_rank=has_rank, cache=ctx.plan_cache(),
-        )
         backend = resolved.backend
         if auto_plan is None:
             auto_plan = resolved.plan
@@ -537,7 +541,8 @@ def _contract_partial_impl(
     perm = tuple(pos[m] for m in keep) + tuple(pos[m] for m in drop)
     if has_rank:
         perm = perm + (node.ndim - 1,)
-    xp = jnp.transpose(node, perm)
+    with _otrace.annotated("repro.engine.relayout"):
+        xp = jnp.transpose(node, perm)
     i_rows = math.prod(keep_sizes) if keep_sizes else 1
     fs = [factors[m] for m in drop]
     itemsize = node.dtype.itemsize
@@ -610,10 +615,11 @@ def _contract_partial_batched(
         canon_shape = (
             math.prod(elem_shape[pos[m]] for m in keep) if keep else 1,
         ) + tuple(elem_shape[pos[m]] for m in drop_t)
-        resolved = resolve(
-            canon_shape, rank, 0, node.dtype, ctx.memory,
-            kind="partial", x_has_rank=has_rank, cache=ctx.plan_cache(),
-        )
+        with _otrace.annotated("repro.engine.resolve"):
+            resolved = resolve(
+                canon_shape, rank, 0, node.dtype, ctx.memory,
+                kind="partial", x_has_rank=has_rank, cache=ctx.plan_cache(),
+            )
         backend = resolved.backend
         plan = plan if plan is not None else resolved.plan
     ectx = _concrete_ctx(ctx, backend)
@@ -626,7 +632,7 @@ def _contract_partial_batched(
     vmapped = jax.vmap(one, in_axes=(0, *axes))
     if not _otrace.should_record(ctx.observe, node, *factors):
         return vmapped(node, *factors)
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated("repro.contract_partial.batched"):
         out = vmapped(node, *factors)
     canon = (
@@ -734,7 +740,7 @@ def multi_ttm(
     if not _otrace.should_record(ctx.observe, x, *concrete_mats):
         return _multi_ttm_impl(x, matrices, keep, ctx, plan, block, out_dtype)
     span: dict = {}
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated(f"repro.multi_ttm.keep{keep}"):
         out = _multi_ttm_impl(
             x, matrices, keep, ctx, plan, block, out_dtype, _span=span,
@@ -750,9 +756,10 @@ def multi_ttm(
 def _record_multi_ttm_span(
     ctx, shape, ranks, keep, itemsize, span, t0, **extra
 ) -> None:
-    """Emit one Multi-TTM dispatch event: resolved backend/plan, the
-    blocked model words (``MultiTTMPlan.model_words``) and the HBL
-    sequential lower bound, clamped at 0."""
+    """Emit one Multi-TTM dispatch event from ``t0`` (ns) to now:
+    resolved backend/plan, the blocked model words
+    (``MultiTTMPlan.model_words``) and the HBL sequential lower bound,
+    clamped at 0."""
     from ..core.bounds import multi_ttm_seq_lb_memory
 
     mem = ctx.memory or Memory.tpu_vmem(itemsize=itemsize)
@@ -765,6 +772,7 @@ def _record_multi_ttm_span(
         )
     _otrace.record_event(
         "multi_ttm",
+        start_ns=t0,
         shape=list(shape),
         ranks=list(ranks),
         keep=keep,
@@ -776,7 +784,6 @@ def _record_multi_ttm_span(
         ),
         memory_words=mem.budget_words,
         itemsize=int(itemsize),
-        wall_time_us=(time.perf_counter() - t0) * 1e6,
         **_dtype_policy(ctx),
         **extra,
     )
@@ -801,31 +808,9 @@ def _multi_ttm_impl(
     keep_key = -1 if keep is None else keep
     canon = _keep_first(x.shape, 0 if keep is None else keep)
     if backend == "auto":
-        # pinned Tucker contexts key decisions by the FULL per-mode rank
-        # tuple (the problem identity); a None matrix at the kept mode
-        # hides R_keep, so such calls just resolve live instead
-        decision = None
-        if all(m is not None for m in matrices):
-            full_ranks = tuple(m.shape[1] for m in matrices)
-            decision = ctx.decision_for(
-                x.shape, full_ranks, keep_key, x.dtype
-            )
-        if decision is None:
-            # lazy import: engine <-> tune layer cycle
-            from ..tune.search import (
-                _is_concrete,
-                resolve_multi_ttm,
-                tune_multi_ttm,
-            )
-
-            if ctx.tune and _is_concrete(x):
-                tune_multi_ttm(
-                    x, matrices, keep, memory=memory, interpret=interpret,
-                    cache=ctx.plan_cache(),
-                )
-            decision = resolve_multi_ttm(
-                canon, ranks, keep_key, x.dtype, memory,
-                cache=ctx.plan_cache(),
+        with _otrace.annotated("repro.engine.resolve"):
+            decision = _resolve_multi_ttm_decision(
+                ctx, x, matrices, keep, canon, ranks, keep_key,
             )
         backend = decision.backend
         plan = plan if plan is not None else decision.plan
@@ -858,7 +843,8 @@ def _multi_ttm_impl(
 
     lead = 0 if keep is None else keep
     perm = (lead,) + tuple(k for k in range(n) if k != lead)
-    xp = jnp.transpose(x, perm)
+    with _otrace.annotated("repro.engine.relayout"):
+        xp = jnp.transpose(x, perm)
     mats = [matrices[k] for k in perm[1:]]
     if plan is None and memory is not None:
         # the keep=None kernel contracts the trailing N-1 modes only (the
@@ -866,9 +852,10 @@ def _multi_ttm_impl(
         kernel_ranks = ranks[1:] if keep is None else ranks
         if mixed:
             memory = memory.with_itemsize(x.dtype.itemsize)
-        plan = choose_multi_ttm_blocks(
-            canon, kernel_ranks, x.dtype.itemsize, memory=memory
-        )
+        with _otrace.annotated("repro.engine.resolve"):
+            plan = choose_multi_ttm_blocks(
+                canon, kernel_ranks, x.dtype.itemsize, memory=memory
+            )
     if _span is not None:
         _span["plan"] = plan
     _count_pallas()
@@ -885,12 +872,39 @@ def _multi_ttm_impl(
         out = out2d.reshape((matrices[0].shape[1],) + rest_ranks)
         out = out.astype(x.dtype)
         return out.astype(out_dtype) if out_dtype is not None else out
-    out = out2d.reshape((x.shape[keep],) + rest_ranks)
     inv = [0] * n
     for pos, axis in enumerate(perm):
         inv[axis] = pos
-    out = jnp.transpose(out, inv).astype(x.dtype)
-    return out.astype(out_dtype) if out_dtype is not None else out
+    with _otrace.annotated("repro.engine.relayout"):
+        out = out2d.reshape((x.shape[keep],) + rest_ranks)
+        out = jnp.transpose(out, inv).astype(x.dtype)
+        return out.astype(out_dtype) if out_dtype is not None else out
+
+
+def _resolve_multi_ttm_decision(ctx, x, matrices, keep, canon, ranks,
+                                keep_key):
+    """The ``auto`` decision of one Multi-TTM: a pinned context's stored
+    decision, else the tune cache (searched first under ``ctx.tune``)."""
+    # pinned Tucker contexts key decisions by the FULL per-mode rank
+    # tuple (the problem identity); a None matrix at the kept mode
+    # hides R_keep, so such calls just resolve live instead
+    decision = None
+    if all(m is not None for m in matrices):
+        full_ranks = tuple(m.shape[1] for m in matrices)
+        decision = ctx.decision_for(x.shape, full_ranks, keep_key, x.dtype)
+    if decision is not None:
+        return decision
+    # lazy import: engine <-> tune layer cycle
+    from ..tune.search import _is_concrete, resolve_multi_ttm, tune_multi_ttm
+
+    if ctx.tune and _is_concrete(x):
+        tune_multi_ttm(
+            x, matrices, keep, memory=ctx.memory, interpret=ctx.interpret,
+            cache=ctx.plan_cache(),
+        )
+    return resolve_multi_ttm(
+        canon, ranks, keep_key, x.dtype, ctx.memory, cache=ctx.plan_cache(),
+    )
 
 
 def _looks_batched_multi_ttm(x, matrices, keep) -> bool:
@@ -947,19 +961,20 @@ def _multi_ttm_batched(x, matrices, keep, ctx, plan, block, out_dtype):
     canon = _keep_first(elem_shape, 0 if keep is None else keep)
     backend = ctx.backend
     if backend == "auto":
-        decision = None
-        if all(m is not None for m in matrices):
-            full_ranks = tuple(int(m.shape[-1]) for m in matrices)
-            decision = ctx.decision_for(
-                elem_shape, full_ranks, keep_key, x.dtype
-            )
-        if decision is None:
-            from ..tune.search import resolve_multi_ttm  # lazy cycle
+        with _otrace.annotated("repro.engine.resolve"):
+            decision = None
+            if all(m is not None for m in matrices):
+                full_ranks = tuple(int(m.shape[-1]) for m in matrices)
+                decision = ctx.decision_for(
+                    elem_shape, full_ranks, keep_key, x.dtype
+                )
+            if decision is None:
+                from ..tune.search import resolve_multi_ttm  # lazy cycle
 
-            decision = resolve_multi_ttm(
-                canon, ranks, keep_key, x.dtype, ctx.memory,
-                cache=ctx.plan_cache(),
-            )
+                decision = resolve_multi_ttm(
+                    canon, ranks, keep_key, x.dtype, ctx.memory,
+                    cache=ctx.plan_cache(),
+                )
         backend = decision.backend
         plan = plan if plan is not None else decision.plan
         block = block if block is not None else decision.block
@@ -974,7 +989,7 @@ def _multi_ttm_batched(x, matrices, keep, ctx, plan, block, out_dtype):
     concrete = [m for m in matrices if m is not None]
     if not _otrace.should_record(ctx.observe, x, *concrete):
         return vmapped(x, *matrices)
-    t0 = time.perf_counter()
+    t0 = _otrace.now_ns()
     with _otrace.annotated(f"repro.multi_ttm.batched.keep{keep}"):
         out = vmapped(x, *matrices)
     span = {"backend": backend, "plan": plan}

@@ -43,8 +43,6 @@ def _fused_pair(x: jax.Array, factors, ctx: ExecutionContext):
     """The sweep's opening ``(B0, P')`` pair. One pallas dispatch on the
     pallas backend; two ``contract_partial`` calls elsewhere (``auto``
     resolves each edge through the tune cache as usual)."""
-    import time
-
     n = x.ndim
     modes = tuple(range(n))
     inner = tuple(range(n - 1))
@@ -61,16 +59,17 @@ def _fused_pair(x: jax.Array, factors, ctx: ExecutionContext):
         plan = None
         if ctx.memory is not None:
             mem = ctx.memory.with_itemsize(x.dtype.itemsize)
-            plan = choose_sweep_blocks(
-                x.shape, fs[0].shape[1], x.dtype.itemsize, memory=mem
-            )
+            with _otrace.annotated("repro.engine.resolve"):
+                plan = choose_sweep_blocks(
+                    x.shape, fs[0].shape[1], x.dtype.itemsize, memory=mem
+                )
         _count_pallas()
         if not _otrace.should_record(ctx.observe, x, *fs):
             return fused_pair_canonical_pallas(
                 x, fs, plan=plan, interpret=ctx.interpret,
                 out_dtype=orig_dtype,
             )
-        t0 = time.perf_counter()
+        t0 = _otrace.now_ns()
         with _otrace.annotated("repro.fused_pair"):
             out = fused_pair_canonical_pallas(
                 x, fs, plan=plan, interpret=ctx.interpret,
@@ -78,12 +77,12 @@ def _fused_pair(x: jax.Array, factors, ctx: ExecutionContext):
             )
         _otrace.record_event(
             "fused_pair",
+            start_ns=t0,
             shape=list(x.shape),
             rank=int(fs[0].shape[1]),
             backend="pallas",
             plan=_span_plan(plan),
             itemsize=int(x.dtype.itemsize),
-            wall_time_us=(time.perf_counter() - t0) * 1e6,
             compute_dtype=ctx.compute_dtype,
             out_dtype=ctx.out_dtype,
         )

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..observe.trace import annotated
 from .common import compiler_params
 from .mttkrpn import krp_contract
 
@@ -93,7 +94,7 @@ def mttkrp3_pallas(
         k_sz // block_k,
     )
     kernel = functools.partial(_mttkrp3_kernel, acc_dtype=acc_dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -107,4 +108,7 @@ def mttkrp3_pallas(
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
         compiler_params=compiler_params(2, 2),
-    )(x, a, b)
+        name="mttkrp3",
+    )
+    with annotated("repro.kernel.mttkrp3"):
+        return call(x, a, b)

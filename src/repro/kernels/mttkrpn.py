@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..observe.trace import annotated
 from .common import compiler_params, fori_leading, mxu_dot, row
 
 
@@ -170,7 +171,7 @@ def mttkrp_partial_pallas(
     kernel = functools.partial(
         _partial_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -178,7 +179,10 @@ def mttkrp_partial_pallas(
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
-    )(x, *factors)
+        name="mttkrp_partial",
+    )
+    with annotated("repro.kernel.mttkrp_partial"):
+        return call(x, *factors)
 
 
 def mttkrpn_pallas(
@@ -226,7 +230,7 @@ def mttkrpn_pallas(
         for d in range(nc)
     ]
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -234,4 +238,7 @@ def mttkrpn_pallas(
         out_shape=jax.ShapeDtypeStruct((i_sz, r_sz), acc_dtype),
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
-    )(x, *factors)
+        name="mttkrpn",
+    )
+    with annotated("repro.kernel.mttkrpn"):
+        return call(x, *factors)

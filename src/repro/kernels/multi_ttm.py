@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..observe.trace import annotated
 from .common import compiler_params, fori_leading, mxu_dot, row
 
 
@@ -142,7 +143,7 @@ def multi_ttm_keep_pallas(
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
     # the kernel's block is (i, r_k, r_1..r_{k-1}); restoring C order
     # moves only the small (I, prod R_d) output
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -152,5 +153,8 @@ def multi_ttm_keep_pallas(
         ),
         interpret=interpret,
         compiler_params=compiler_params(1, nc),
-    )(x, *matrices)
+        name="multi_ttm",
+    )
+    with annotated("repro.kernel.multi_ttm"):
+        out = call(x, *matrices)
     return jnp.swapaxes(out, 1, 2).reshape(i_sz, r_lead * ranks[-1])

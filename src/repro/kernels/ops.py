@@ -31,6 +31,7 @@ from ..engine.plan import (  # noqa: F401  (re-exported planner API)
     choose_multi_ttm_blocks,
     mttkrp_traffic_model,
 )
+from ..observe.trace import annotated
 from .mttkrp3 import mttkrp3_pallas
 from .mttkrpn import mttkrp_partial_pallas, mttkrpn_pallas
 from .multi_ttm import multi_ttm_keep_pallas
@@ -75,11 +76,12 @@ def mttkrp_canonical_pallas(
         plan = choose_blocks(xp.shape, rank, xp.dtype.itemsize)
     tgt = plan.padded_shape(xp.shape)
     r_pad = _round_up(rank, plan.block_r)
-    xp = jnp.pad(xp, [(0, t - s) for t, s in zip(tgt, xp.shape)])
-    fs = [
-        jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
-        for d, f in enumerate(fs)
-    ]
+    with annotated("repro.engine.relayout"):
+        xp = jnp.pad(xp, [(0, t - s) for t, s in zip(tgt, xp.shape)])
+        fs = [
+            jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
+            for d, f in enumerate(fs)
+        ]
     if n == 3 and variant != "generic":
         out = mttkrp3_pallas(
             xp, fs[0], fs[1],
@@ -97,8 +99,9 @@ def mttkrp_canonical_pallas(
             block_r=plan.block_r,
             interpret=interpret,
         )
-    out = out[:out_rows, :rank]
-    return out.astype(out_dtype) if out_dtype is not None else out
+    with annotated("repro.engine.relayout"):
+        out = out[:out_rows, :rank]
+        return out.astype(out_dtype) if out_dtype is not None else out
 
 
 def mttkrp_pallas(
@@ -121,7 +124,8 @@ def mttkrp_pallas(
     if n < 3:
         raise ValueError("pallas kernel supports N >= 3 (use core.mttkrp)")
     perm = (mode,) + tuple(k for k in range(n) if k != mode)
-    xp = jnp.transpose(x, perm)
+    with annotated("repro.engine.relayout"):
+        xp = jnp.transpose(x, perm)
     fs = [factors[k] for k in perm[1:]]
     return mttkrp_canonical_pallas(
         xp, fs, plan=plan, interpret=interpret,
@@ -152,15 +156,16 @@ def mttkrp_partial_canonical_pallas(
         )
     tgt = plan.padded_shape(node.shape[:-1])
     r_pad = _round_up(rank, plan.block_r)
-    node = jnp.pad(
-        node,
-        [(0, t - s) for t, s in zip(tgt, node.shape[:-1])]
-        + [(0, r_pad - rank)],
-    )
-    fs = [
-        jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
-        for d, f in enumerate(fs)
-    ]
+    with annotated("repro.engine.relayout"):
+        node = jnp.pad(
+            node,
+            [(0, t - s) for t, s in zip(tgt, node.shape[:-1])]
+            + [(0, r_pad - rank)],
+        )
+        fs = [
+            jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
+            for d, f in enumerate(fs)
+        ]
     out = mttkrp_partial_pallas(
         node, fs,
         block_i=plan.block_i,
@@ -168,8 +173,9 @@ def mttkrp_partial_canonical_pallas(
         block_r=plan.block_r,
         interpret=interpret,
     )
-    out = out[:out_rows, :rank]
-    return out.astype(out_dtype) if out_dtype is not None else out
+    with annotated("repro.engine.relayout"):
+        out = out[:out_rows, :rank]
+        return out.astype(out_dtype) if out_dtype is not None else out
 
 
 def multi_ttm_canonical_pallas(
@@ -196,19 +202,21 @@ def multi_ttm_canonical_pallas(
     if plan is None:
         plan = choose_multi_ttm_blocks(xp.shape, ranks, xp.dtype.itemsize)
     tgt = plan.padded_shape(xp.shape)
-    xp = jnp.pad(xp, [(0, t - s) for t, s in zip(tgt, xp.shape)])
-    mats = [
-        jnp.pad(m, ((0, tgt[1 + d] - m.shape[0]), (0, 0)))
-        for d, m in enumerate(mats)
-    ]
+    with annotated("repro.engine.relayout"):
+        xp = jnp.pad(xp, [(0, t - s) for t, s in zip(tgt, xp.shape)])
+        mats = [
+            jnp.pad(m, ((0, tgt[1 + d] - m.shape[0]), (0, 0)))
+            for d, m in enumerate(mats)
+        ]
     out = multi_ttm_keep_pallas(
         xp, mats,
         block_i=plan.block_i,
         block_contract=plan.block_contract,
         interpret=interpret,
     )
-    out = out[:out_rows]
-    return out.astype(out_dtype) if out_dtype is not None else out
+    with annotated("repro.engine.relayout"):
+        out = out[:out_rows]
+        return out.astype(out_dtype) if out_dtype is not None else out
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..observe.trace import annotated
 from .common import compiler_params
 from .mttkrpn import krp_contract
 
@@ -118,7 +119,7 @@ def mttkrp_fused_pair_pallas(
     kernel = functools.partial(
         _fused_pair_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -132,7 +133,10 @@ def mttkrp_fused_pair_pallas(
         ),
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
-    )(x, *factors)
+        name="sweep",
+    )
+    with annotated("repro.kernel.sweep"):
+        return call(x, *factors)
 
 
 def fused_pair_canonical_pallas(
@@ -161,11 +165,12 @@ def fused_pair_canonical_pallas(
         plan = choose_sweep_blocks(x.shape, rank, x.dtype.itemsize)
     tgt = plan.padded_shape(x.shape)
     r_pad = _round_up(rank, plan.block_r)
-    x = jnp.pad(x, [(0, t - s) for t, s in zip(tgt, x.shape)])
-    fs = [
-        jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
-        for d, f in enumerate(fs)
-    ]
+    with annotated("repro.engine.relayout"):
+        x = jnp.pad(x, [(0, t - s) for t, s in zip(tgt, x.shape)])
+        fs = [
+            jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
+            for d, f in enumerate(fs)
+        ]
     b0, p = mttkrp_fused_pair_pallas(
         x, fs,
         block_i=plan.block_i,
@@ -173,10 +178,11 @@ def fused_pair_canonical_pallas(
         block_r=plan.block_r,
         interpret=interpret,
     )
-    b0 = b0[:orig_shape[0], :rank]
-    p = p[
-        tuple(slice(0, s) for s in orig_shape[:-1]) + (slice(0, rank),)
-    ]
-    if out_dtype is not None:
-        return b0.astype(out_dtype), p.astype(out_dtype)
-    return b0, p
+    with annotated("repro.engine.relayout"):
+        b0 = b0[:orig_shape[0], :rank]
+        p = p[
+            tuple(slice(0, s) for s in orig_shape[:-1]) + (slice(0, rank),)
+        ]
+        if out_dtype is not None:
+            return b0.astype(out_dtype), p.astype(out_dtype)
+        return b0, p

@@ -141,7 +141,10 @@ class DecompositionServer:
     With ``ctx.observe`` on and an active :class:`repro.observe.Trace`,
     every flush records one ``serve_request`` span per request (queue
     and execute phase seconds) and one ``serve_bucket`` span per bucket
-    (batch size, padded shape, cold/warm).
+    (batch size, padded shape, cold/warm); with ``annotate`` on, the
+    profiler sees ``repro.serve.flush`` and, per bucket,
+    ``repro.serve.pack`` and ``repro.serve.unpack`` around the batched
+    driver's own spans.
     """
 
     def __init__(
@@ -188,21 +191,14 @@ class DecompositionServer:
     def flush(self) -> dict[str, ServeResult]:
         """Execute the queue: one batched call per bucket; returns
         ``{request_id: ServeResult}`` and empties the queue."""
-        from ..core.tensor import random_factors
-        from ..engine.batch import cp_als_batched
+        with _otrace.annotated("repro.serve.flush"):
+            return self._flush()
 
-        queue, self._queue = self._queue, []
-        buckets: dict[str, list[Request]] = {}
-        for req in queue:
-            buckets.setdefault(req.key, []).append(req)
-        out: dict[str, ServeResult] = {}
-        for key, reqs in buckets.items():
-            t_exec0 = time.perf_counter()
-            cold = key not in self._seen_buckets
-            self._seen_buckets.add(key)
-            padded = bucket_shape(reqs[0].x.shape, self.pad_to)
-            rank = reqs[0].rank
-            dtype = reqs[0].x.dtype
+    def _pack(self, reqs: list[Request], padded, rank: int, dtype):
+        """The bucket's stacked, zero-padded tensors and initial factors."""
+        from ..core.tensor import random_factors
+
+        with _otrace.annotated("repro.serve.pack"):
             xs = jnp.stack(
                 [pad_to_bucket(r.x.astype(dtype), padded) for r in reqs]
             )
@@ -222,6 +218,24 @@ class DecompositionServer:
                 jnp.stack([init[k] for init in inits])
                 for k in range(len(padded))
             ]
+            return xs, init_factors
+
+    def _flush(self) -> dict[str, ServeResult]:
+        from ..engine.batch import cp_als_batched
+
+        queue, self._queue = self._queue, []
+        buckets: dict[str, list[Request]] = {}
+        for req in queue:
+            buckets.setdefault(req.key, []).append(req)
+        out: dict[str, ServeResult] = {}
+        for key, reqs in buckets.items():
+            t_exec0 = time.perf_counter()
+            cold = key not in self._seen_buckets
+            self._seen_buckets.add(key)
+            padded = bucket_shape(reqs[0].x.shape, self.pad_to)
+            rank = reqs[0].rank
+            dtype = reqs[0].x.dtype
+            xs, init_factors = self._pack(reqs, padded, rank, dtype)
             res = cp_als_batched(
                 xs, rank, n_iters=self.n_iters,
                 init_factors=init_factors, tol=self.tol, ctx=self.ctx,
@@ -240,22 +254,23 @@ class DecompositionServer:
                     execute_s=execute_s,
                 )
             for b, r in enumerate(reqs):
-                out[r.request_id] = sr = ServeResult(
-                    request_id=r.request_id,
-                    factors=[
-                        f[b, : r.x.shape[k]]
-                        for k, f in enumerate(res.factors)
-                    ],
-                    weights=res.weights[b],
-                    fit=float(res.fits[b]),
-                    n_iters=int(res.n_iters[b]),
-                    converged=bool(res.converged[b]),
-                    bucket=key,
-                    batch=len(reqs),
-                    queue_s=t_exec0 - r.enqueued_at,
-                    execute_s=execute_s,
-                    cold=cold,
-                )
+                with _otrace.annotated("repro.serve.unpack"):
+                    out[r.request_id] = sr = ServeResult(
+                        request_id=r.request_id,
+                        factors=[
+                            f[b, : r.x.shape[k]]
+                            for k, f in enumerate(res.factors)
+                        ],
+                        weights=res.weights[b],
+                        fit=float(res.fits[b]),
+                        n_iters=int(res.n_iters[b]),
+                        converged=bool(res.converged[b]),
+                        bucket=key,
+                        batch=len(reqs),
+                        queue_s=t_exec0 - r.enqueued_at,
+                        execute_s=execute_s,
+                        cold=cold,
+                    )
                 if _otrace.should_record(self.ctx.observe):
                     _otrace.record_event(
                         "serve_request",

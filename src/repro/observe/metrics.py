@@ -9,11 +9,10 @@ writes to:
 ``engine.pallas_dispatches``        counter — kernel-path contractions
 ``tune.cache_hits`` / ``_misses``   counters — plan-cache resolution
 ``tune.candidates_measured``        counter — autotune measurements run
-``tune.search_time_us``             histogram — per-search wall time
-``distributed.sweep_collective_bytes``
-                                    histogram — HLO-measured bytes of one
-                                    distributed ALS/HOOI sweep program
 ``trace.events_dropped``            counter — ring-buffer evictions
+
+(A search's time and a distributed sweep's measured collective bytes
+ride on the ``tune_search`` and ``*_sweep_collectives`` trace events.)
 
 Reads are *snapshot-based*: measure a code region with
 
@@ -41,8 +40,6 @@ PALLAS_DISPATCHES = "engine.pallas_dispatches"
 TUNE_CACHE_HITS = "tune.cache_hits"
 TUNE_CACHE_MISSES = "tune.cache_misses"
 TUNE_CANDIDATES = "tune.candidates_measured"
-TUNE_SEARCH_TIME_US = "tune.search_time_us"
-SWEEP_COLLECTIVE_BYTES = "distributed.sweep_collective_bytes"
 TRACE_EVENTS_DROPPED = "trace.events_dropped"
 
 

@@ -8,9 +8,11 @@ Reads a trace exported by :class:`repro.observe.trace.Trace` and prints
 one markdown table row per dispatch-like event, with the model /
 measured / bound columns the paper's claims live in:
 
-| # | kind | problem | backend | model (words) | bound (words) | measured (bytes) | x model | flag |
+| # | kind | problem | backend | ms | model (words) | bound (words) | measured (bytes) | x model | flag |
 
-``x model`` is measured bytes over modeled bytes (events without a
+``ms`` is the event's interval, ``end_ns - start_ns`` (schema
+``repro.observe.Span/2``; ``-`` for an instant event or an older
+trace).  ``x model`` is measured bytes over modeled bytes (events without a
 measured side — ordinary dispatch spans — show ``-``; collective-sweep
 and bounds-audit events have one).  Any event whose measured traffic
 exceeds its model by more than ``--flag-factor`` (default 2.0) is
@@ -57,6 +59,13 @@ def _problem(e: dict) -> str:
     return " ".join(bits) or e.get("name", "-")
 
 
+def _ms(e: dict) -> str:
+    start, end = e.get("start_ns"), e.get("end_ns")
+    if start is None or end is None or end <= start:
+        return "-"
+    return f"{(end - start) * 1e-6:.3f}"
+
+
 def _fmt(v, digits: int = 0) -> str:
     if v is None:
         return "-"
@@ -94,7 +103,7 @@ def render_rows(
             flagged += 1
         rows.append(
             f"| {e.get('seq', '-')} | {kind} | {_problem(e)} "
-            f"| {e.get('backend', '-')} "
+            f"| {e.get('backend', '-')} | {_ms(e)} "
             f"| {_fmt(modeled)} | {_fmt(bound)} | {_fmt(measured)} "
             f"| {_fmt(ratio, 2) if ratio is not None else '-'} "
             f"| {flag} |"
@@ -145,11 +154,11 @@ def main(argv: list[str] | None = None) -> int:
         )
         return 1
     print(
-        "| # | kind | problem | backend | model (words) | bound (words) "
-        "| measured (bytes) | x model | flag |"
+        "| # | kind | problem | backend | ms | model (words) "
+        "| bound (words) | measured (bytes) | x model | flag |"
     )
-    print("|---|------|---------|---------|---------------|---------------"
-          "|------------------|---------|------|")
+    print("|---|------|---------|---------|----|---------------"
+          "|---------------|------------------|---------|------|")
     for r in rows:
         print(r)
     print(

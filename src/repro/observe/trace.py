@@ -3,23 +3,31 @@
 A :class:`Trace` is a context manager that captures *span events* — one
 dict per engine dispatch / driver iteration / distributed sweep — into an
 in-memory ring buffer, with a JSONL exporter (one event per line, stable
-schema) and ``jax.named_scope`` / ``jax.profiler.TraceAnnotation``
-annotations so observed dispatches are visible in TPU profiler traces::
+schema) and ``jax.profiler.TraceAnnotation`` spans (:func:`annotated`)
+over the hot path, so the host work behind every device-idle gap has a
+name in TPU profiler traces::
 
     ctx = repro.ExecutionContext.create(observe=True)
     with repro.Trace(path="run.jsonl") as t:
         repro.cp_als(x, rank=8, ctx=ctx)
     t.events                    # the recorded span dicts
-    # run.jsonl: one JSON object per line, schema repro.observe.Span/1
+    # run.jsonl: one JSON object per line, schema repro.observe.Span/2
 
-Every event carries ``schema`` / ``seq`` / ``time_s`` / ``kind`` plus
-kind-specific fields.  Engine dispatch events (``kind`` in ``mttkrp`` /
+Every event carries ``schema`` / ``seq`` / ``start_ns`` / ``end_ns`` /
+``kind`` plus kind-specific fields.  ``start_ns``/``end_ns`` are
+nanoseconds on the clock the profiler stamps its spans with (the host's
+wall clock, ``time.time_ns``): a dispatch event spans the dispatch, a
+driver iteration its sweep, an instant event has ``start_ns == end_ns``.
+An ``.xplane.pb`` stores its spans relative to the ``profile_start_time``
+of its ``Task Environment`` plane; add that to join the two.
+
+Engine dispatch events (``kind`` in ``mttkrp`` /
 ``contract_partial`` / ``multi_ttm`` / ``fused_pair``) record the
 resolved backend, the block plan, the modeled traffic in words
 (``BlockPlan.eq10_words`` / ``MultiTTMPlan.model_words`` — the paper's
 Eq (10) and its Multi-TTM analog), the memory-dependent sequential lower
 bound (``seq_lb_memory``, clamped at 0), the dtype policy, and the
-dispatch wall time.  Driver events (``cp_als_iter`` / ``tucker_iter``)
+dispatch interval.  Driver events (``cp_als_iter`` / ``tucker_iter``)
 record per-iteration fit / λ / convergence; distributed sweep events
 (``cp_sweep_collectives`` / ``tucker_sweep_collectives``) record
 HLO-measured collective bytes next to the sweep cost model.
@@ -39,6 +47,24 @@ Recording is *driver-side only*: when the operands are jax tracers (the
 call is being traced into a jit/shard_map program) nothing runs — no
 event, no annotation — so compiled HLO is byte-identical with observe
 on or off, and shard_map sweep bodies stay collective-clean.
+
+Recording never waits for the device: array-valued fields (a sweep's
+weights, a batch's fits) stay device arrays on the event and are
+converted once, when :attr:`Trace.events` or :meth:`Trace.export` reads
+them, so a traced program syncs exactly where the untraced one does.
+
+Spans
+-----
+:func:`annotated` is the one span helper of the hot path: a
+``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` for a sweep)
+opened while a ``Trace(annotate=True)`` is active and no jit/shard_map
+program is being staged.  Names are prefixes by layer: drivers
+``repro.cp_als*`` / ``repro.tucker*``; engine ``repro.mttkrp.*``,
+``repro.multi_ttm.*``, ``repro.contract_partial*``, ``repro.fused_pair``
+and, inside them, ``repro.engine.resolve`` / ``repro.engine.relayout``;
+kernel launches ``repro.kernel.<name>`` (``<name>`` is also the
+``pallas_call``'s ``name=``); serving ``repro.serve.*``.  The profiler
+gives a device-idle stretch to the innermost span open in it.
 """
 
 from __future__ import annotations
@@ -46,16 +72,16 @@ from __future__ import annotations
 import json
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import nullcontext
 from typing import Any, Iterable
 
 from .metrics import TRACE_EVENTS_DROPPED, registry
 
-SPAN_SCHEMA = "repro.observe.Span/1"
+SPAN_SCHEMA = "repro.observe.Span/2"
 
 #: Keys every event carries, in emission order (the round-trip contract
 #: tests pin; kind-specific fields follow these).
-BASE_FIELDS = ("schema", "seq", "time_s", "kind")
+BASE_FIELDS = ("schema", "seq", "start_ns", "end_ns", "kind")
 
 _ACTIVE: list["Trace"] = []
 
@@ -68,8 +94,7 @@ class Trace:
     ``path`` exports the buffer as JSONL on clean exit;
     ``capture`` is ``"all"`` (record every engine call) or
     ``"observed"`` (record only ``ExecutionContext.observe=True`` calls);
-    ``annotate`` wraps observed dispatches in ``jax.named_scope`` +
-    ``jax.profiler.TraceAnnotation`` so they appear in profiler traces.
+    ``annotate`` opens the hot path's profiler spans (:func:`annotated`).
     """
 
     def __init__(
@@ -106,14 +131,21 @@ class Trace:
             self.export(self.path)
 
     # -- recording -----------------------------------------------------------
-    def record(self, kind: str, **fields: Any) -> dict:
-        """Append one span event (ring-buffered) and return it."""
+    def record(
+        self, kind: str, *, start_ns: int | None = None, **fields: Any
+    ) -> dict:
+        """Append one span event (ring-buffered) and return it.  It ends
+        now; ``start_ns`` (from :func:`now_ns`) gives its start, else it
+        is an instant.  Device-array fields are kept as they are (no
+        sync) until the events are read."""
         if len(self._buf) == self._buf.maxlen:
             registry().inc(TRACE_EVENTS_DROPPED)
+        end_ns = now_ns()
         event = {
             "schema": SPAN_SCHEMA,
             "seq": self._seq,
-            "time_s": time.time(),
+            "start_ns": end_ns if start_ns is None else int(start_ns),
+            "end_ns": end_ns,
             "kind": kind,
         }
         event.update(fields)
@@ -123,7 +155,12 @@ class Trace:
 
     @property
     def events(self) -> list[dict]:
-        """The buffered span events, oldest first (a copy)."""
+        """The buffered span events, oldest first (a copy); array fields
+        are converted to lists here, once per array."""
+        for event in self._buf:
+            for k, v in event.items():
+                if hasattr(v, "__array__"):
+                    event[k] = _to_json(v)
         return list(self._buf)
 
     def __len__(self) -> int:
@@ -138,6 +175,19 @@ class Trace:
             for e in events:
                 f.write(json.dumps(e, sort_keys=True) + "\n")
         return len(events)
+
+
+def now_ns() -> int:
+    """Nanoseconds on the profiler's clock (the host's wall clock)."""
+    return time.time_ns()
+
+
+def _to_json(value: Any) -> Any:
+    """One device-to-host read of an array field: a list (or scalar) of
+    Python numbers."""
+    import numpy as np
+
+    return np.asarray(value).tolist()
 
 
 def current_trace() -> Trace | None:
@@ -178,27 +228,43 @@ def should_record(ctx_observe: bool, *arrays: Any) -> bool:
     return not _is_tracer(*arrays)
 
 
-def record_event(kind: str, **fields: Any) -> dict | None:
+def record_event(
+    kind: str, *, start_ns: int | None = None, **fields: Any
+) -> dict | None:
     """Record into the active trace (no-op without one)."""
     t = current_trace()
     if t is None:
         return None
-    return t.record(kind, **fields)
+    return t.record(kind, start_ns=start_ns, **fields)
 
 
-@contextmanager
-def annotated(name: str):
-    """``jax.named_scope`` + profiler annotation around one observed
-    dispatch — only entered when the active trace asks for annotations
-    (and never under tracing; see :func:`should_record`)."""
-    t = current_trace()
-    if t is None or not t.annotate:
-        yield
-        return
+_NO_SPAN = nullcontext()
+
+
+def _staging() -> bool:
+    """Is a jit/shard_map/make_jaxpr program being staged?  Eager
+    ``vmap`` is not staging: its batch tracers run op by op on the
+    device, so the host time they take is real and gets its span."""
     import jax
 
-    with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.core.unsafe_am_i_under_a_jit_DO_NOT_USE()
+
+
+def annotated(name: str, *, step: int | None = None):
+    """The profiler span ``name`` (a ``StepTraceAnnotation`` numbered
+    ``step`` when given): entered only while a ``Trace(annotate=True)``
+    is active and nothing is being staged into a compiled program, so
+    compiled HLO is byte-identical with tracing on or off.  With no
+    trace active it costs one list lookup."""
+    if not _ACTIVE or not _ACTIVE[-1].annotate or _staging():
+        return _NO_SPAN
+    # host spans only: no jax.named_scope, which would also rename the
+    # ops the eager calls inside it lower
+    import jax
+
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 def summarize_events(events: Iterable[dict]) -> dict:

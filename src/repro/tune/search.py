@@ -354,7 +354,6 @@ def search(
     executors; ``metric="walltime"`` times everything.
     """
     from ..observe import trace as _otrace
-    from ..observe.metrics import TUNE_SEARCH_TIME_US, registry
 
     _search_t0 = time.perf_counter()
     if ctx is not None:
@@ -402,7 +401,6 @@ def search(
     _assign_scores(measurements, metric)
     winner = min(ok, key=lambda m: m.walltime_us).candidate
     search_us = (time.perf_counter() - _search_t0) * 1e6
-    registry().observe(TUNE_SEARCH_TIME_US, search_us)
     if _otrace.should_record(ctx.observe if ctx is not None else False):
         _otrace.record_event(
             "tune_search",

@@ -16,7 +16,7 @@ each other and with ``--only``.
 
 ``--trace-out`` records one ``kind="static_verify"`` span event per
 verdict (kernel, per-grid comm point, dtype program) plus one summary
-event, in the standard ``repro.observe.Span/1`` schema, so
+event, in the standard ``repro.observe.Span/2`` schema, so
 ``python -m repro.observe.report`` tables static verdicts — including
 the per-grid modeled/bound/measured byte columns — next to measured
 bounds-audit rows.

@@ -70,7 +70,8 @@ def test_span_schema_and_jsonl_roundtrip(tmp_path):
     assert e["backend"] in ("einsum", "blocked_host", "pallas")
     assert e["modeled_words"] > 0
     assert e["lower_bound_words"] >= 0
-    assert e["wall_time_us"] > 0
+    # the dispatch interval, on the profiler's clock (ns since the epoch)
+    assert e["end_ns"] > e["start_ns"] > 1.5e18
     assert "compute_dtype" in e and "out_dtype" in e
     # the JSONL round-trip is exact (events are pure JSON)
     back = load_trace(str(p))
@@ -140,6 +141,195 @@ def test_observe_flag_does_not_change_hlo():
         off = lower_text(False)
         assert len(tr) == 0  # nothing recorded while tracing either
     assert on == off
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans: one helper, never under staging, never syncing
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def span_names(monkeypatch):
+    """The names of the profiler spans the program opens, in order (the
+    annotations are replaced by recorders; nothing else changes)."""
+    import contextlib
+
+    names: list[str] = []
+
+    def recorder(name, **kw):
+        names.append(name)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", recorder)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", recorder)
+    return names
+
+
+def _pallas_ctx():
+    return ExecutionContext.create(backend="pallas", interpret=True)
+
+
+def test_spans_cover_drivers_engine_kernels_and_serving(span_names):
+    """Eagerly, every layer opens its spans; without a Trace (or with
+    annotate=False) none opens."""
+    from repro.engine.batch import cp_als_batched
+    from repro.launch.serve import DecompositionServer
+
+    x, fs = _problem()
+    repro.cp_als(x, RANK, n_iters=1, init_factors=fs, ctx=_pallas_ctx())
+    with Trace(annotate=False):
+        repro.cp_als(x, RANK, n_iters=1, init_factors=fs, ctx=_pallas_ctx())
+    assert span_names == []
+    with Trace():
+        repro.cp_als(x, RANK, n_iters=2, init_factors=fs, ctx=_pallas_ctx())
+        cp_als_batched(jnp.stack([x, x]), RANK, n_iters=1, ctx=_pallas_ctx())
+        repro.tucker_hooi(x, (2, 2, 2), n_iters=1, ctx=_pallas_ctx())
+        server = DecompositionServer(_pallas_ctx(), n_iters=1, tol=0.0)
+        server.submit(x, RANK)
+        server.flush()
+    got = set(span_names)
+    for want in (
+        "repro.cp_als", "repro.cp_als.sweep", "repro.cp_als.update",
+        "repro.cp_als.fit", "repro.mttkrp.mode0", "repro.engine.relayout",
+        "repro.kernel.mttkrp3", "repro.cp_als_batched",
+        "repro.cp_als_batched.sweep", "repro.cp_als_batched.update",
+        "repro.cp_als_batched.fit", "repro.mttkrp.batched.mode2",
+        "repro.tucker_hooi", "repro.tucker.hosvd_init",
+        "repro.tucker.sweep", "repro.tucker.eigh", "repro.tucker.fit",
+        "repro.multi_ttm.keep1", "repro.kernel.multi_ttm",
+        "repro.serve.flush", "repro.serve.pack", "repro.serve.unpack",
+    ):
+        assert want in got, want
+    # the eager vmap of a batched dispatch still launches a named kernel
+    assert span_names.count("repro.kernel.mttkrp3") == 2 * 3 + 3 + 3
+    assert span_names.count("repro.cp_als.sweep") == 2
+
+
+def test_resolve_span_on_the_auto_backend(span_names):
+    x, fs = _problem()
+    with Trace():
+        repro.mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="auto"))
+    assert span_names == ["repro.mttkrp.mode0", "repro.engine.resolve"]
+
+
+def test_no_span_is_entered_under_jit(span_names):
+    """Staging a program opens no span: not the engine's, the kernels'
+    or the drivers' pieces that jit can trace."""
+    from repro.core.tucker import hosvd_init
+    from repro.kernels import ops as kernel_ops
+
+    x, fs = _problem()
+    ctx = _pallas_ctx()
+    with Trace():
+        jax.jit(lambda a, *b: repro.mttkrp(a, list(b), 1, ctx=ctx))(x, *fs)
+        jax.jit(lambda a, *b: repro.mttkrp(a, list(b), 0, ctx=ctx))(
+            jnp.stack([x, x]), *fs)
+        jax.jit(lambda a, *m: repro.multi_ttm(a, list(m), keep=2, ctx=ctx))(
+            x, *[f[:, :2] for f in fs])
+        jax.jit(lambda a: kernel_ops.mttkrp_pallas(a, fs, 2, interpret=True))(x)
+        jax.jit(lambda a: hosvd_init(a, (2, 2, 2)))(x)
+        assert span_names == []
+        repro.mttkrp(x, fs, 1, ctx=ctx)  # eager: the same call opens spans
+    assert span_names[0] == "repro.mttkrp.mode1"
+
+
+def test_new_span_sites_do_not_change_hlo():
+    """Byte-identical HLO with a Trace active or not, through every kind
+    of new span site: relayout, kernel launch, resolve, batched vmap."""
+    from repro.kernels import ops as kernel_ops
+
+    x, fs = _problem()
+    auto = ExecutionContext.create(backend="auto")
+    calls = [
+        (lambda a, *b: kernel_ops.mttkrp_pallas(a, b, 0, interpret=True),
+         (x, *fs)),
+        (lambda a, *b: repro.mttkrp(a, list(b), 2, ctx=_pallas_ctx()),
+         (jnp.stack([x, x]), *fs)),
+        (lambda a, *b: repro.mttkrp(a, list(b), 1, ctx=auto), (x, *fs)),
+        (lambda a, *m: repro.multi_ttm(a, list(m), keep=0,
+                                       ctx=_pallas_ctx()),
+         (x, *[f[:, :2] for f in fs])),
+    ]
+    for fn, args in calls:
+        off = jax.jit(fn).lower(*args).as_text()
+        with Trace() as tr:
+            on = jax.jit(fn).lower(*args).as_text()
+        assert on == off
+        assert len(tr) == 0
+
+
+@pytest.fixture
+def no_host_reads(monkeypatch):
+    """Device-to-host reads fail: the transfer guard (what fires on a
+    TPU) and, since a CPU array needs no transfer, a read of an array's
+    host value too."""
+    from jax._src import array
+
+    def refuse(self):
+        raise AssertionError("a device array was read on the host")
+
+    monkeypatch.setattr(array.ArrayImpl, "_value", property(refuse))
+    with jax.transfer_guard_device_to_host("disallow"):
+        yield
+
+
+def test_traced_batched_solve_syncs_where_untraced_does(no_host_reads):
+    """tol=0: neither the untraced nor the traced batched solve reads a
+    device value; the trace converts its arrays when read."""
+    from repro.engine.batch import cp_als_batched
+
+    x, _ = _problem()
+    xb = jnp.stack([x, 2 * x])
+    cp_als_batched(xb, RANK, n_iters=3, tol=0.0)
+    with Trace() as tr:
+        cp_als_batched(xb, RANK, n_iters=3, tol=0.0)
+    assert len(tr) > 0
+
+
+def test_recorded_arrays_convert_once_when_read(tmp_path):
+    from repro.engine.batch import cp_als_batched
+
+    x, fs = _problem()
+    p = tmp_path / "t.jsonl"
+    with Trace(path=str(p)) as tr:
+        repro.cp_als(x, RANK, n_iters=2, init_factors=fs)
+        r = cp_als_batched(jnp.stack([x, x]), RANK, n_iters=2)
+    it = next(e for e in tr.events if e["kind"] == "cp_als_iter")
+    assert isinstance(it["weights"], list) and len(it["weights"]) == RANK
+    bi = [e for e in tr.events if e["kind"] == "cp_als_batched_iter"]
+    assert bi[-1]["fits"] == pytest.approx([float(f) for f in r.fits])
+    assert bi[-1]["converged"] == [False, False]
+    assert load_trace(str(p)) == tr.events
+
+
+def test_event_intervals_match_profiler_sweep_spans(tmp_path):
+    """Each cp_als_iter event spans its repro.cp_als.sweep step span in
+    the .xplane.pb, on the same clock (the profiler stores spans relative
+    to its Task Environment's profile_start_time)."""
+    import glob
+    import os
+
+    x, fs = _problem()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with Trace() as tr:
+            repro.cp_als(x, RANK, n_iters=3, init_factors=fs)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    t0 = dict(pd.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    spans = sorted(
+        (t0 + e.start_ns, t0 + e.end_ns)
+        for plane in pd.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name == "repro.cp_als.sweep"
+    )
+    iters = [e for e in tr.events if e["kind"] == "cp_als_iter"]
+    assert len(spans) == len(iters) == 3
+    for (s, e), ev in zip(spans, iters):
+        assert abs(ev["start_ns"] - s) < 1e6 and abs(ev["end_ns"] - e) < 1e6
 
 
 # ---------------------------------------------------------------------------
