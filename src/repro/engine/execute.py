@@ -65,7 +65,9 @@ from .plan import (
     best_uniform_block,
     choose_blocks,
     choose_multi_ttm_blocks,
+    mttkrp_lane_pos,
 )
+from .plan import mode_first as _mode_first
 
 BACKENDS = ("einsum", "blocked_host", "pallas")
 
@@ -204,7 +206,11 @@ def _record_mttkrp_span(
         plan = choose_blocks(
             mode_first, rank, itemsize, memory=mem,
             x_has_rank=bool(span.get("x_has_rank", False)),
+            lane_pos=mttkrp_lane_pos(len(shape), mode)
+            if kind == "mttkrp" else -1,
         )
+    if kind == "mttkrp":
+        extra.setdefault("relayout", span.get("relayout", "none"))
     event = {
         "shape": list(shape),
         "rank": int(rank),
@@ -278,31 +284,28 @@ def _mttkrp_impl(
         return out.astype(out_dtype) if out_dtype is not None else out
     from ..kernels import ops as kernel_ops  # lazy: avoids import cycle
 
-    if plan is None and memory is not None:
+    if plan is None:
         rank = next(
             f.shape[1] for k, f in enumerate(factors) if k != mode
         )
-        if mixed:
+        if mixed and memory is not None:
             # dtype-aware planning: same physical budget, narrower items
             memory = memory.with_itemsize(x.dtype.itemsize)
         with _otrace.annotated("repro.engine.resolve"):
-            plan = choose_blocks(
-                _mode_first(x.shape, mode), rank, x.dtype.itemsize,
-                memory=memory,
+            plan = kernel_ops.mttkrp_plan(
+                x.shape, rank, mode, x.dtype.itemsize, memory=memory,
+                variant=kernel_variant,
             )
     if _span is not None:
         _span["plan"] = plan
         _span["variant"] = kernel_variant
+        _span["relayout"] = kernel_ops.tensor_relayout(
+            x.shape, mode, plan, kernel_variant
+        )
     _count_pallas()
     return kernel_ops.mttkrp_pallas(
         x, factors, mode, plan=plan, interpret=interpret,
         out_dtype=out_dtype, variant=kernel_variant,
-    )
-
-
-def _mode_first(shape: Sequence[int], mode: int) -> tuple[int, ...]:
-    return (shape[mode],) + tuple(
-        s for k, s in enumerate(shape) if k != mode
     )
 
 
@@ -384,11 +387,12 @@ def _mttkrp_batched(
         block = block if block is not None else decision.block
         kernel_variant = kernel_variant or decision.variant
     ectx = _concrete_ctx(ctx, backend)
+    span: dict = {}
 
     def one(xb, *fbs):
         return _mttkrp_impl(
             xb, list(fbs), mode, ectx, plan, block, out_dtype,
-            kernel_variant,
+            kernel_variant, _span=span,
         )
 
     vmapped = jax.vmap(one, in_axes=(0, *axes))
@@ -397,7 +401,6 @@ def _mttkrp_batched(
     t0 = _otrace.now_ns()
     with _otrace.annotated(f"repro.mttkrp.batched.mode{mode}"):
         out = vmapped(x, *factors)
-    span = {"backend": backend, "plan": plan}
     _record_mttkrp_span(
         "mttkrp", ectx, elem_shape, rank, mode, x.dtype.itemsize, span,
         t0, batch=batch,
@@ -541,8 +544,7 @@ def _contract_partial_impl(
     perm = tuple(pos[m] for m in keep) + tuple(pos[m] for m in drop)
     if has_rank:
         perm = perm + (node.ndim - 1,)
-    with _otrace.annotated("repro.engine.relayout"):
-        xp = jnp.transpose(node, perm)
+    xp = kernel_ops.transpose_tensor(node, perm)
     i_rows = math.prod(keep_sizes) if keep_sizes else 1
     fs = [factors[m] for m in drop]
     itemsize = node.dtype.itemsize
@@ -843,8 +845,7 @@ def _multi_ttm_impl(
 
     lead = 0 if keep is None else keep
     perm = (lead,) + tuple(k for k in range(n) if k != lead)
-    with _otrace.annotated("repro.engine.relayout"):
-        xp = jnp.transpose(x, perm)
+    xp = kernel_ops.transpose_tensor(x, perm)
     mats = [matrices[k] for k in perm[1:]]
     if plan is None and memory is not None:
         # the keep=None kernel contracts the trailing N-1 modes only (the

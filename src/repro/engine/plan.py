@@ -210,6 +210,24 @@ class BlockPlan:
         }
 
 
+def mode_first(shape: Sequence[int], mode: int) -> tuple[int, ...]:
+    """``shape`` reordered output mode first, the other modes after it in
+    axis order: the order every :class:`BlockPlan` is written in."""
+    return (shape[mode],) + tuple(s for k, s in enumerate(shape) if k != mode)
+
+
+def mttkrp_lane_pos(ndim: int, mode: int, variant: str | None = None) -> int:
+    """Position, in a :class:`BlockPlan`'s output-mode-first order, of the
+    block the lane width aligns: the block of the tensor's minor axis as
+    the kernel reads it.  The 3-way kernel reads X in its stored layout
+    (unless ``variant="generic"``), whose lane axis (axis 2) comes first
+    when it is the output mode; every other kernel reads a mode-first
+    copy, whose lane axis is last."""
+    if ndim == 3 and mode == 2 and variant != "generic":
+        return 0
+    return ndim - 1
+
+
 def choose_blocks(
     shape: Sequence[int],
     rank: int,
@@ -218,13 +236,18 @@ def choose_blocks(
     *,
     memory: Memory | None = None,
     x_has_rank: bool = False,
+    lane_pos: int = -1,
 ) -> BlockPlan:
     """Pick TPU-aligned block sizes fitting the memory budget.
 
     Strategy (mirrors the paper's b ~ (alpha*M)^{1/N} with TPU alignment):
     output mode and rank tiles start at MXU-friendly 128; the minor
-    contraction dim at 128 (lane), other contraction dims at 8 (sublane);
-    then shrink the largest contributor until the working set fits.
+    contraction dim at 128, other contraction dims at 8; then shrink the
+    largest contributor until the working set fits.  ``shape`` is in
+    output-mode-first order; ``lane_pos`` is the position in it of the
+    tensor's lane axis as the kernel reads it (:func:`mttkrp_lane_pos`;
+    the last by default).  That block is a multiple of the lane width
+    (128), every other block a multiple of the sublane count (8).
 
     Degenerate extents never over-pad: a dimension smaller than its
     alignment unit (a mode of size 1, a rank below the lane width) gets
@@ -239,6 +262,7 @@ def choose_blocks(
         memory = Memory.tpu_vmem(vmem_budget, itemsize)
     lane, sublane = memory.lane, memory.sublane
     n = len(shape)
+    units = [lane if p == lane_pos % n else sublane for p in range(n)]
 
     def start(extent: int, unit: int, pref: int) -> int:
         if extent <= unit:  # sub-unit dim: full extent, zero padding
@@ -248,19 +272,15 @@ def choose_blocks(
     def floor(extent: int, unit: int) -> int:
         return max(1, extent) if extent <= unit else unit
 
-    bi = start(shape[0], sublane, 128)
+    bi = start(shape[0], units[0], 128)
     br = start(rank, lane, 512)
-    bc: list[int] = []
-    for d in range(1, n):
-        if d == n - 1:  # minor dim: lane-aligned
-            bc.append(start(shape[d], lane, 128))
-        else:
-            bc.append(start(shape[d], sublane, max(sublane, 8)))
-    fi = floor(shape[0], sublane)
-    fr = floor(rank, lane)
-    fc = [
-        floor(shape[d], lane if d == n - 1 else sublane) for d in range(1, n)
+    bc = [
+        start(shape[d], units[d], 128 if d == n - 1 else max(sublane, 8))
+        for d in range(1, n)
     ]
+    fi = floor(shape[0], units[0])
+    fr = floor(rank, lane)
+    fc = [floor(shape[d], units[d]) for d in range(1, n)]
     plan = BlockPlan(bi, tuple(bc), br, x_has_rank)
     # shrink until it fits (keep alignment floors)
     while not plan.fits(memory):

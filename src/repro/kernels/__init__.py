@@ -3,8 +3,9 @@
 ``mttkrp3``/``mttkrpn`` — the blocked MTTKRP (Algorithm 2 adapted to VMEM +
 MXU); ``multi_ttm`` — the blocked Kronecker-weight Multi-TTM (the
 Tucker/HOSVD kernel, arXiv:2207.10437); ``ssd_intra`` — the fused
-intra-chunk SSD contraction (same blocking discipline, §Perf Cell B). ``ops`` wraps with mode canonicalization,
-padding, and VMEM-budget block planning; ``ref`` holds the jnp oracles.
+intra-chunk SSD contraction (same blocking discipline, §Perf Cell B). ``ops`` wraps with layout (the 3-way
+kernel reads X in place; N > 3 reads a mode-first copy), padding where a
+shape needs it, and VMEM-budget block planning; ``ref`` holds the jnp oracles.
 All validated in interpret mode on CPU; compiled via Mosaic on TPU.
 """
 

@@ -154,7 +154,12 @@ def fused_pair_canonical_pallas(
     factors for axes 1..N-1 in order. Returns ``(b0, p)`` un-padded, with
     ``p`` of shape ``(I_0, I_1..I_{N-2}, R)``.
     """
-    from .ops import _auto_interpret, _round_up  # local: shared idiom
+    from .ops import (  # local: shared idiom
+        _auto_interpret,
+        _crop,
+        _pad_operands,
+        _round_up,
+    )
 
     interpret = _auto_interpret() if interpret is None else interpret
     rank = fs[0].shape[1]
@@ -165,12 +170,7 @@ def fused_pair_canonical_pallas(
         plan = choose_sweep_blocks(x.shape, rank, x.dtype.itemsize)
     tgt = plan.padded_shape(x.shape)
     r_pad = _round_up(rank, plan.block_r)
-    with annotated("repro.engine.relayout"):
-        x = jnp.pad(x, [(0, t - s) for t, s in zip(tgt, x.shape)])
-        fs = [
-            jnp.pad(f, ((0, tgt[1 + d] - f.shape[0]), (0, r_pad - rank)))
-            for d, f in enumerate(fs)
-        ]
+    x, fs = _pad_operands(x, tgt, fs, [(t, r_pad) for t in tgt[1:]])
     b0, p = mttkrp_fused_pair_pallas(
         x, fs,
         block_i=plan.block_i,
@@ -178,11 +178,7 @@ def fused_pair_canonical_pallas(
         block_r=plan.block_r,
         interpret=interpret,
     )
-    with annotated("repro.engine.relayout"):
-        b0 = b0[:orig_shape[0], :rank]
-        p = p[
-            tuple(slice(0, s) for s in orig_shape[:-1]) + (slice(0, rank),)
-        ]
-        if out_dtype is not None:
-            return b0.astype(out_dtype), p.astype(out_dtype)
-        return b0, p
+    return (
+        _crop(b0, (orig_shape[0], rank), out_dtype),
+        _crop(p, tuple(orig_shape[:-1]) + (rank,), out_dtype),
+    )

@@ -124,7 +124,7 @@ def audit_mttkrp(
     from ..core.bounds import seq_lb_memory
     from ..engine.context import ExecutionContext
     from ..engine.execute import _mode_first, mttkrp
-    from ..engine.plan import Memory, choose_blocks
+    from ..engine.plan import Memory, choose_blocks, mttkrp_lane_pos
 
     if ctx is None:
         ctx = ExecutionContext.default()
@@ -132,7 +132,8 @@ def audit_mttkrp(
     itemsize = x.dtype.itemsize
     mem = ctx.memory or Memory.tpu_vmem(itemsize=itemsize)
     plan = choose_blocks(
-        _mode_first(x.shape, mode), rank, itemsize, memory=mem
+        _mode_first(x.shape, mode), rank, itemsize, memory=mem,
+        lane_pos=mttkrp_lane_pos(x.ndim, mode),
     )
     modeled = plan.eq10_words(_mode_first(x.shape, mode), rank)
     lb = max(seq_lb_memory(x.shape, rank, mem.budget_words), 0.0)

@@ -7,6 +7,8 @@ dependency-free (no jax import) process-local registry every layer
 writes to:
 
 ``engine.pallas_dispatches``        counter — kernel-path contractions
+``engine.tensor_relayouts``         counter — tensor-sized transposes and
+                                    pads the kernel path dispatches
 ``tune.cache_hits`` / ``_misses``   counters — plan-cache resolution
 ``tune.candidates_measured``        counter — autotune measurements run
 ``trace.events_dropped``            counter — ring-buffer evictions
@@ -37,6 +39,7 @@ from typing import Mapping
 
 #: Canonical metric names (importable so call sites cannot typo them).
 PALLAS_DISPATCHES = "engine.pallas_dispatches"
+TENSOR_RELAYOUTS = "engine.tensor_relayouts"
 TUNE_CACHE_HITS = "tune.cache_hits"
 TUNE_CACHE_MISSES = "tune.cache_misses"
 TUNE_CANDIDATES = "tune.candidates_measured"

@@ -109,16 +109,20 @@ def cache_key(
 
     ``rank`` is the CP rank (int) or — for ``kind="multi_ttm"`` — the
     tuple of per-mode Tucker ranks (tagged ``r1xr2x...``); ``mode`` is
-    the output/kept mode (``-1`` = full Tucker core, no kept mode)."""
+    the output/kept mode (``-1`` = full Tucker core, no kept mode).  A
+    3-way MTTKRP key is tagged ``layout=stored``: its specialized kernel
+    reads X in place, so an entry tuned against a transposed copy of X
+    (untagged) misses instead of replaying its plan."""
     shape_tag = "x".join(str(int(s)) for s in shape)
     if isinstance(rank, (tuple, list)):
         rank_tag = "x".join(str(int(r)) for r in rank)
     else:
         rank_tag = str(int(rank))
+    layout = "|layout=stored" if kind == "mttkrp" and len(shape) == 3 else ""
     return (
         f"{kind}|shape={shape_tag}|rank={rank_tag}|mode={int(mode)}"
         f"|dtype={jax.numpy.dtype(dtype).name}|mem={memory_tag(memory)}"
-        f"|platform={jax.default_backend()}|jax={jax.__version__}"
+        f"{layout}|platform={jax.default_backend()}|jax={jax.__version__}"
     )
 
 

@@ -47,6 +47,7 @@ from ..engine.plan import (
     MultiTTMPlan,
     choose_blocks,
     choose_multi_ttm_blocks,
+    mttkrp_lane_pos,
     uniform_plan,
 )
 from .cache import CacheEntry, PlanCache, cache_key, default_cache, plan_to_dict
@@ -160,11 +161,14 @@ def candidate_plans(
     *,
     x_has_rank: bool = False,
     max_plans: int = 8,
+    lane_pos: int = -1,
 ) -> list[BlockPlan]:
-    """The pallas plan candidates: analytic best, its perturbations, and
-    the paper's exact uniform-b plan."""
+    """The pallas plan candidates: analytic best (lane-aligned at
+    ``lane_pos``, as :func:`~repro.engine.plan.choose_blocks`), its
+    perturbations, and the paper's exact uniform-b plan."""
     base = choose_blocks(
-        shape, rank, itemsize, memory=memory, x_has_rank=x_has_rank
+        shape, rank, itemsize, memory=memory, x_has_rank=x_has_rank,
+        lane_pos=lane_pos,
     )
     plans: list[BlockPlan] = [base]
     plans.extend(_perturbations(base, shape, rank, memory))
@@ -195,8 +199,10 @@ def generate_candidates(
     *,
     backends: Sequence[str] = ("einsum", "blocked_host", "pallas"),
     max_plans: int = 8,
+    lane_pos: int = -1,
 ) -> list[Candidate]:
-    """All executors x all plan candidates x (3-way) both kernel variants."""
+    """All executors x all plan candidates x (3-way) both kernel variants.
+    ``lane_pos`` aligns the analytic plan (:func:`candidate_plans`)."""
     out: list[Candidate] = []
     n = len(shape)
     if "einsum" in backends:
@@ -208,7 +214,8 @@ def generate_candidates(
     if "pallas" in backends and n >= 3:
         variants = KERNEL_VARIANTS if n == 3 else ("generic",)
         for plan in candidate_plans(
-            shape, rank, memory, itemsize, max_plans=max_plans
+            shape, rank, memory, itemsize, max_plans=max_plans,
+            lane_pos=lane_pos,
         ):
             for variant in variants:
                 out.append(Candidate("pallas", plan=plan, variant=variant))
@@ -367,7 +374,8 @@ def search(
     mem = memory or Memory.tpu_vmem(itemsize=x.dtype.itemsize)
     key = cache_key(perm_shape, rank, mode, x.dtype, mem)
     cands = generate_candidates(
-        perm_shape, rank, mem, x.dtype.itemsize, max_plans=max_plans
+        perm_shape, rank, mem, x.dtype.itemsize, max_plans=max_plans,
+        lane_pos=mttkrp_lane_pos(x.ndim, mode),
     )
     def tm_bytes(c):
         return int(
@@ -872,7 +880,9 @@ def resolve(
         )
     if jax.default_backend() == "tpu" and len(shape) >= 3:
         plan = choose_blocks(
-            shape, rank, itemsize, memory=mem, x_has_rank=x_has_rank
+            shape, rank, itemsize, memory=mem, x_has_rank=x_has_rank,
+            lane_pos=mttkrp_lane_pos(len(shape), mode)
+            if kind == "mttkrp" else -1,
         )
         return Resolved("pallas", plan, None, None, False, key)
     return Resolved("einsum", None, None, None, False, key)

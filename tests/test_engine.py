@@ -27,7 +27,11 @@ from repro.engine import (
 )
 from repro.engine.plan import uniform_plan
 from repro.kernels.ref import mttkrp_ref
-from repro.observe.metrics import PALLAS_DISPATCHES, registry
+from repro.observe.metrics import (
+    PALLAS_DISPATCHES,
+    TENSOR_RELAYOUTS,
+    registry,
+)
 
 
 def _dispatches() -> int:
@@ -155,6 +159,33 @@ def test_explicit_plan_padding_path():
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 2), rtol=5e-4, atol=5e-4
     )
+
+
+@pytest.mark.parametrize("dims,labels", [
+    ((16, 16, 16), ["none", "none", "none"]),       # aligned: in place
+    ((16, 12, 20), ["pad", "pad", "pad"]),          # off the blocks: pads
+    ((8, 6, 5, 4), ["none", "transpose", "transpose", "transpose"]),
+])
+def test_tensor_relayouts_count_what_reaches_x(dims, labels):
+    """``engine.tensor_relayouts`` counts the tensor-sized transposes and
+    pads of a cp_als sweep, and each ``mttkrp`` event names them: none
+    for an aligned 3-way tensor (every mode reads X in place), one pad a
+    mode for an unaligned one, a transpose a mode for a 4-way tensor's
+    modes 1..3 (the generic kernel reads a mode-first copy)."""
+    from repro import ExecutionContext
+    from repro.observe import Trace
+
+    x, _ = _mk(dims, 3, seed=6)
+    ctx = ExecutionContext.create(
+        backend="pallas", interpret=True, observe=True
+    )
+    before = registry().snapshot()
+    with Trace() as tr:
+        cp_als(x, 3, n_iters=1, ctx=ctx, sweep="per_mode")
+    counted = registry().delta(before).get(TENSOR_RELAYOUTS, 0)
+    events = [e for e in tr.events if e["kind"] == "mttkrp"]
+    assert [e["relayout"] for e in events] == labels
+    assert counted == sum(l.count("+") + 1 for l in labels if l != "none")
 
 
 # --------------------------------------------------------------------------

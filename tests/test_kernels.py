@@ -15,6 +15,7 @@ from repro.kernels.ops import (
     mttkrp_traffic_model,
 )
 from repro.kernels.ref import mttkrp_ref
+from repro.observe.metrics import TENSOR_RELAYOUTS, registry
 
 # interpret-mode kernel sweeps dominate the suite's wall time
 pytestmark = pytest.mark.slow
@@ -38,15 +39,54 @@ def _mk(dims, rank, seed=0, dtype=jnp.float32):
     return x, fs
 
 
-@pytest.mark.parametrize("dims", SHAPES_3)
-@pytest.mark.parametrize("rank", [1, 4, 16])
-def test_kernel3_all_modes(dims, rank):
-    x, fs = _mk(dims, rank)
-    for mode in range(3):
+# (dims, rank, mode, form): every shape of SHAPES_3 at three ranks in
+# every mode (aligned shapes such as (8, 8, 8) and (64, 64, 64) run with
+# no relayout, the others with one pad), the zero-padding case, and the
+# batched vmap form with per-element and with shared factors
+CASES_3 = [
+    (dims, rank, mode, "single")
+    for dims in SHAPES_3 for rank in (1, 4, 16) for mode in range(3)
+] + [((7, 7, 7), 3, 1, "single")] + [
+    (dims, 4, mode, form)
+    for dims in ((8, 8, 8), (5, 12, 9)) for mode in range(3)
+    for form in ("per_element", "shared")
+]
+
+
+@pytest.mark.parametrize("dims,rank,mode,form", CASES_3)
+def test_kernel3_all_modes(dims, rank, mode, form):
+    """The 3-way kernel reads X in its stored layout for every output
+    mode: it matches the einsum MTTKRP, and at most one relayout (a pad,
+    never a transpose) reaches X."""
+    batch = 3
+    if form == "single":
+        x, fs = _mk(dims, rank)
+    else:
+        x, fs = _mk((batch,) + dims, rank, seed=mode)
+        if form == "per_element":
+            fs = [
+                jax.random.normal(jax.random.PRNGKey(9 + k), (batch, d, rank))
+                for k, d in enumerate(dims)
+            ]
+        else:
+            fs = fs[1:]
+    before = registry().snapshot()
+    if form == "single":
         out = mttkrp_pallas(x, fs, mode, interpret=True)
-        np.testing.assert_allclose(
-            out, mttkrp_ref(x, fs, mode), rtol=2e-4, atol=2e-4
-        )
+        want = mttkrp_ref(x, fs, mode)
+    else:
+        axes = 0 if form == "per_element" else None
+        out = jax.vmap(
+            lambda xb, *fb: mttkrp_pallas(xb, fb, mode, interpret=True),
+            in_axes=(0,) + (axes,) * 3,
+        )(x, *fs)
+        want = jnp.stack([
+            mttkrp_ref(x[b], [f[b] if axes == 0 else f for f in fs], mode)
+            for b in range(batch)
+        ])
+    assert registry().delta(before).get(TENSOR_RELAYOUTS, 0) <= 1
+    assert out.shape == want.shape
+    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("dims", SHAPES_4)
@@ -141,15 +181,6 @@ def test_traffic_model_rank_tiling():
     m = mttkrp_traffic_model(dims, rank, plan)
     gr = -(-2048 // plan.block_r)
     assert m["x_bytes"] == 256 ** 3 * 4 * gr
-
-
-def test_kernel_zero_padding_exactness():
-    """Padded rows/cols must not pollute real outputs (zeros in X kill any
-    padded-factor garbage)."""
-    x, fs = _mk((7, 7, 7), 3, seed=4)
-    out = mttkrp_pallas(x, fs, 1, interpret=True)
-    assert out.shape == (7, 3)
-    np.testing.assert_allclose(out, mttkrp_ref(x, fs, 1), rtol=2e-4, atol=2e-4)
 
 
 def test_kernel_jit_compatible():

@@ -13,6 +13,7 @@ time, so only the test worker given this file loads the TPU library.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -75,6 +76,34 @@ def test_mttkrp3_compiles(chip):
         ),
         shape, _factors(shape, rank, skip=0),
     )
+
+
+@pytest.mark.parametrize("mode", [0, 1, 2])
+@pytest.mark.parametrize("shape", [(1024, 1024, 1024), (8, 256, 256, 256)])
+def test_mttkrp3_reads_x_in_place(chip, shape, mode):
+    """The jitted engine MTTKRP of every mode, alone and batched (a
+    leading B = 8 with per-element factors, as the server's bucket runs
+    it), holds no tensor-sized temporary: X reaches the kernel without a
+    transposed or padded copy."""
+    import repro
+
+    ctx = repro.ExecutionContext.create(backend="pallas", interpret=False)
+    rank = 64
+    lead = shape[:-3]
+
+    def sds(s):
+        return jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)
+
+    compiled = jax.jit(
+        lambda x, fs: repro.mttkrp(x, fs, mode, ctx=ctx)
+    ).lower(
+        sds(shape), [sds(lead + (s, rank)) for s in shape[-3:]]
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    tensor_bytes = 4 * math.prod(shape)
+    element_bytes = 4 * math.prod(shape[-3:])
+    assert compiled.memory_analysis().temp_size_in_bytes < element_bytes // 8
+    assert compiled.memory_analysis().output_size_in_bytes < tensor_bytes // 64
 
 
 @pytest.mark.parametrize(
