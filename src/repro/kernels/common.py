@@ -8,10 +8,15 @@ a collapse of the leading axes into the sublane axis, and combines the
 other contraction axes afterwards: the sublane axis in the same step,
 the leading axes one slab at a time in :func:`fori_leading`.  That also
 bounds the in-kernel temporaries by one slab instead of one tile.
+
+Each kernel's launcher is a :func:`launcher`: its ``pallas_call`` is
+built once per static signature, not on every eager call.
 """
 
 from __future__ import annotations
 
+import functools
+import inspect
 import math
 from typing import Callable, Sequence
 
@@ -19,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..observe.metrics import LAUNCHER_TRACES, registry
 
 #: Scoped VMEM each kernel may use.  Mosaic's default (16 MiB) must hold
 #: the double-buffered BlockSpec tiles at their (8, 128)-padded size plus
@@ -37,6 +44,40 @@ def compiler_params(n_parallel: int, n_arbitrary: int) -> pltpu.CompilerParams:
         + ("arbitrary",) * n_arbitrary,
         vmem_limit_bytes=VMEM_LIMIT_BYTES,
     )
+
+
+#: Every :func:`launcher`, for :func:`clear_launcher_caches`.
+_LAUNCHERS: list = []
+
+
+def launcher(body: Callable) -> Callable:
+    """``body``, which builds one ``pallas_call`` and runs it, as a
+    ``jax.jit`` whose keyword-only arguments are static.  The kernel is
+    then traced, lowered and compiled once per static signature (shapes
+    and dtypes come in through the avals); a later eager call at that
+    signature takes JAX's compiled-dispatch fast path, and an eager
+    ``vmap`` reuses the batched program.  Each trace counts one
+    ``kernels.launcher_traces``."""
+    static = tuple(
+        name for name, p in inspect.signature(body).parameters.items()
+        if p.kind is p.KEYWORD_ONLY
+    )
+
+    @functools.wraps(body)
+    def traced(*args, **kwargs):
+        registry().inc(LAUNCHER_TRACES)
+        return body(*args, **kwargs)
+
+    jitted = jax.jit(traced, static_argnames=static)
+    _LAUNCHERS.append(jitted)
+    return jitted
+
+
+def clear_launcher_caches() -> None:
+    """Drop every launcher's traced and compiled programs, so that the
+    next call at any signature builds its ``pallas_call`` again."""
+    for f in _LAUNCHERS:
+        f.clear_cache()
 
 
 def mxu_dot(

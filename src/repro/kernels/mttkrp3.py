@@ -41,7 +41,8 @@ merged leading axes; Mosaic refuses to merge the (sublane, lane) tile):
   ``O^T(r, b2) = W^T X(b0·b1, b2)``: X enters the MXU untransposed as its
   stationary operand, with b2 output columns. (The other orientation,
   ``X^T W``, ran 1.3x slower on a v5e at 1024^3, rank 64.) The kernel's
-  ``(R, I2)`` output is transposed once, a factor-sized copy.
+  ``(R, I2)`` output is transposed once, a factor-sized copy in the same
+  compiled launch.
 
 Only the N > 3 kernel, the generic variant and the partial kernel read a
 mode-first copy (``kernels/ops.py``).
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..observe.trace import annotated
-from .common import compiler_params, mxu_dot
+from .common import compiler_params, launcher, mxu_dot
 from .mttkrpn import krp_contract
 
 
@@ -117,6 +118,17 @@ def mttkrp3_pallas(
         assert f.shape == (x.shape[k], r_sz)
     assert all(s % b == 0 for s, b in zip(x.shape, blocks))
     assert r_sz % block_r == 0
+    with annotated("repro.kernel.mttkrp3"):
+        return _mttkrp3(
+            x, tuple(factors), mode=mode, blocks=tuple(blocks),
+            block_r=block_r, interpret=interpret, acc_dtype=acc_dtype,
+        )
+
+
+@launcher
+def _mttkrp3(x, factors, *, mode, blocks, block_r, interpret, acc_dtype):
+    axes = tuple(k for k in range(3) if k != mode)
+    r_sz = factors[0].shape[1]
 
     def x_map(o, r, ca, cb):
         idx = [o, o, o]
@@ -142,11 +154,11 @@ def mttkrp3_pallas(
     kernel = functools.partial(
         _mttkrp3_kernel, mode=mode, acc_dtype=acc_dtype
     )
-    call = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(tuple(blocks), x_map),
+            pl.BlockSpec(blocks, x_map),
             pl.BlockSpec(
                 (blocks[axes[0]], block_r), lambda o, r, ca, cb: (ca, r)
             ),
@@ -159,7 +171,5 @@ def mttkrp3_pallas(
         interpret=interpret,
         compiler_params=compiler_params(2, 2),
         name="mttkrp3",
-    )
-    with annotated("repro.kernel.mttkrp3"):
-        out = call(x, *factors)
+    )(x, *factors)
     return out.T if mode == 2 else out

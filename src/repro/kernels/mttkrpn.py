@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..observe.trace import annotated
-from .common import compiler_params, fori_leading, mxu_dot, row
+from .common import compiler_params, fori_leading, launcher, mxu_dot, row
 
 
 def krp_contract(x_ref, f_refs, acc_dtype, p_ref=None) -> jax.Array:
@@ -137,13 +137,26 @@ def mttkrp_partial_pallas(
     required; returns ``(I, R)`` in ``acc_dtype``."""
     nc = x.ndim - 2
     assert len(factors) == nc and len(block_contract) == nc
-    i_sz = x.shape[0]
     r_sz = x.shape[-1]
     for d, f in enumerate(factors):
         assert f.shape == (x.shape[1 + d], r_sz)
         assert x.shape[1 + d] % block_contract[d] == 0
-    assert i_sz % block_i == 0 and r_sz % block_r == 0
+    assert x.shape[0] % block_i == 0 and r_sz % block_r == 0
+    with annotated("repro.kernel.mttkrp_partial"):
+        return _mttkrp_partial(
+            x, tuple(factors), block_i=block_i,
+            block_contract=tuple(block_contract), block_r=block_r,
+            interpret=interpret, acc_dtype=acc_dtype,
+        )
 
+
+@launcher
+def _mttkrp_partial(
+    x, factors, *, block_i, block_contract, block_r, interpret, acc_dtype
+):
+    nc = x.ndim - 2
+    i_sz = x.shape[0]
+    r_sz = x.shape[-1]
     grid = (
         r_sz // block_r,
         i_sz // block_i,
@@ -161,9 +174,7 @@ def mttkrp_partial_pallas(
         return (i, r)
 
     in_specs = [
-        pl.BlockSpec(
-            (block_i,) + tuple(block_contract) + (block_r,), x_map
-        )
+        pl.BlockSpec((block_i,) + block_contract + (block_r,), x_map)
     ] + [
         pl.BlockSpec((block_contract[d], block_r), f_map_for(d))
         for d in range(nc)
@@ -171,7 +182,7 @@ def mttkrp_partial_pallas(
     kernel = functools.partial(
         _partial_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    call = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -180,9 +191,7 @@ def mttkrp_partial_pallas(
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
         name="mttkrp_partial",
-    )
-    with annotated("repro.kernel.mttkrp_partial"):
-        return call(x, *factors)
+    )(x, *factors)
 
 
 def mttkrpn_pallas(
@@ -197,16 +206,28 @@ def mttkrpn_pallas(
 ) -> jax.Array:
     """Canonical mode-0 N-way MTTKRP. ``factors`` are the N-1 non-output
     factors in tensor-axis order (axes 1..N-1). Pre-padded inputs required."""
-    n = x.ndim
-    nc = n - 1
+    nc = x.ndim - 1
     assert len(factors) == nc and len(block_contract) == nc
-    i_sz = x.shape[0]
     r_sz = factors[0].shape[1]
     for d, f in enumerate(factors):
         assert f.shape == (x.shape[1 + d], r_sz)
         assert x.shape[1 + d] % block_contract[d] == 0
-    assert i_sz % block_i == 0 and r_sz % block_r == 0
+    assert x.shape[0] % block_i == 0 and r_sz % block_r == 0
+    with annotated("repro.kernel.mttkrpn"):
+        return _mttkrpn(
+            x, tuple(factors), block_i=block_i,
+            block_contract=tuple(block_contract), block_r=block_r,
+            interpret=interpret, acc_dtype=acc_dtype,
+        )
 
+
+@launcher
+def _mttkrpn(
+    x, factors, *, block_i, block_contract, block_r, interpret, acc_dtype
+):
+    nc = x.ndim - 1
+    i_sz = x.shape[0]
+    r_sz = factors[0].shape[1]
     grid = (
         r_sz // block_r,
         i_sz // block_i,
@@ -224,13 +245,13 @@ def mttkrpn_pallas(
         return (i, r)
 
     in_specs = [
-        pl.BlockSpec((block_i,) + tuple(block_contract), x_map)
+        pl.BlockSpec((block_i,) + block_contract, x_map)
     ] + [
         pl.BlockSpec((block_contract[d], block_r), f_map_for(d))
         for d in range(nc)
     ]
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
-    call = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -239,6 +260,4 @@ def mttkrpn_pallas(
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
         name="mttkrpn",
-    )
-    with annotated("repro.kernel.mttkrpn"):
-        return call(x, *factors)
+    )(x, *factors)

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..observe.trace import annotated
-from .common import compiler_params, fori_leading, mxu_dot, row
+from .common import compiler_params, fori_leading, launcher, mxu_dot, row
 
 
 def _spread(r: int, s: int, outer: bool) -> jax.Array:
@@ -111,12 +111,23 @@ def multi_ttm_keep_pallas(
     nc = x.ndim - 1
     assert nc >= 2, "the kernel contracts at least two modes"
     assert len(matrices) == nc and len(block_contract) == nc
-    i_sz = x.shape[0]
-    ranks = tuple(m.shape[1] for m in matrices)
     for d, m in enumerate(matrices):
         assert m.shape[0] == x.shape[1 + d]
         assert x.shape[1 + d] % block_contract[d] == 0
-    assert i_sz % block_i == 0
+    assert x.shape[0] % block_i == 0
+    with annotated("repro.kernel.multi_ttm"):
+        return _multi_ttm(
+            x, tuple(matrices), block_i=block_i,
+            block_contract=tuple(block_contract), interpret=interpret,
+            acc_dtype=acc_dtype,
+        )
+
+
+@launcher
+def _multi_ttm(x, matrices, *, block_i, block_contract, interpret, acc_dtype):
+    nc = x.ndim - 1
+    i_sz = x.shape[0]
+    ranks = tuple(m.shape[1] for m in matrices)
     r_lead = math.prod(ranks[:-1])
 
     grid = (i_sz // block_i,) + tuple(
@@ -135,7 +146,7 @@ def multi_ttm_keep_pallas(
         return (i, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((block_i,) + tuple(block_contract), x_map)
+        pl.BlockSpec((block_i,) + block_contract, x_map)
     ] + [
         pl.BlockSpec((block_contract[d], ranks[d]), m_map_for(d))
         for d in range(nc)
@@ -143,7 +154,7 @@ def multi_ttm_keep_pallas(
     kernel = functools.partial(_kernel, n_contract=nc, acc_dtype=acc_dtype)
     # the kernel's block is (i, r_k, r_1..r_{k-1}); restoring C order
     # moves only the small (I, prod R_d) output
-    call = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -154,7 +165,5 @@ def multi_ttm_keep_pallas(
         interpret=interpret,
         compiler_params=compiler_params(1, nc),
         name="multi_ttm",
-    )
-    with annotated("repro.kernel.multi_ttm"):
-        out = call(x, *matrices)
+    )(x, *matrices)
     return jnp.swapaxes(out, 1, 2).reshape(i_sz, r_lead * ranks[-1])

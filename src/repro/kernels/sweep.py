@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..observe.trace import annotated
-from .common import compiler_params
+from .common import compiler_params, launcher
 from .mttkrpn import krp_contract
 
 
@@ -78,17 +78,29 @@ def mttkrp_fused_pair_pallas(
     tensor pass. ``factors`` are the N-1 non-output factors in tensor-axis
     order (axes 1..N-1). Pre-padded inputs required; both outputs are in
     ``acc_dtype``."""
-    n = x.ndim
-    nc = n - 1
+    nc = x.ndim - 1
     assert nc >= 2, "fused pair needs >= 2 contraction dims"
     assert len(factors) == nc and len(block_contract) == nc
-    i_sz = x.shape[0]
     r_sz = factors[0].shape[1]
     for d, f in enumerate(factors):
         assert f.shape == (x.shape[1 + d], r_sz)
         assert x.shape[1 + d] % block_contract[d] == 0
-    assert i_sz % block_i == 0 and r_sz % block_r == 0
+    assert x.shape[0] % block_i == 0 and r_sz % block_r == 0
+    with annotated("repro.kernel.sweep"):
+        return _fused_pair(
+            x, tuple(factors), block_i=block_i,
+            block_contract=tuple(block_contract), block_r=block_r,
+            interpret=interpret, acc_dtype=acc_dtype,
+        )
 
+
+@launcher
+def _fused_pair(
+    x, factors, *, block_i, block_contract, block_r, interpret, acc_dtype
+):
+    nc = x.ndim - 1
+    i_sz = x.shape[0]
+    r_sz = factors[0].shape[1]
     grid = (
         r_sz // block_r,
         i_sz // block_i,
@@ -109,17 +121,17 @@ def mttkrp_fused_pair_pallas(
         return (i,) + cs[:-1] + (r,)
 
     in_specs = [
-        pl.BlockSpec((block_i,) + tuple(block_contract), x_map)
+        pl.BlockSpec((block_i,) + block_contract, x_map)
     ] + [
         pl.BlockSpec((block_contract[d], block_r), f_map_for(d))
         for d in range(nc)
     ]
     p_shape = (i_sz,) + tuple(x.shape[1 + d] for d in range(nc - 1)) + (r_sz,)
-    p_block = (block_i,) + tuple(block_contract[:-1]) + (block_r,)
+    p_block = (block_i,) + block_contract[:-1] + (block_r,)
     kernel = functools.partial(
         _fused_pair_kernel, n_contract=nc, acc_dtype=acc_dtype
     )
-    call = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
@@ -134,9 +146,7 @@ def mttkrp_fused_pair_pallas(
         interpret=interpret,
         compiler_params=compiler_params(2, nc),
         name="sweep",
-    )
-    with annotated("repro.kernel.sweep"):
-        return call(x, *factors)
+    )(x, *factors)
 
 
 def fused_pair_canonical_pallas(

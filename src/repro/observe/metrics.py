@@ -9,6 +9,10 @@ writes to:
 ``engine.pallas_dispatches``        counter — kernel-path contractions
 ``engine.tensor_relayouts``         counter — tensor-sized transposes and
                                     pads the kernel path dispatches
+``kernels.launcher_traces``         counter — Pallas kernel launchers
+                                    traced (one per new static signature;
+                                    over ``engine.pallas_dispatches`` it is
+                                    the launch cache's miss share)
 ``tune.cache_hits`` / ``_misses``   counters — plan-cache resolution
 ``tune.candidates_measured``        counter — autotune measurements run
 ``trace.events_dropped``            counter — ring-buffer evictions
@@ -43,6 +47,7 @@ from typing import Mapping
 #: Canonical metric names (importable so call sites cannot typo them).
 PALLAS_DISPATCHES = "engine.pallas_dispatches"
 TENSOR_RELAYOUTS = "engine.tensor_relayouts"
+LAUNCHER_TRACES = "kernels.launcher_traces"
 TUNE_CACHE_HITS = "tune.cache_hits"
 TUNE_CACHE_MISSES = "tune.cache_misses"
 TUNE_CANDIDATES = "tune.candidates_measured"

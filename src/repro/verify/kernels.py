@@ -71,9 +71,15 @@ def capture_pallas_calls() -> Iterator[list[KernelCapture]]:
     """Patch ``jax.experimental.pallas.pallas_call`` with a recorder that
     returns zeros of the declared output aval (trace under
     ``jax.eval_shape`` so nothing materializes).  Yields the capture
-    list; restores the real ``pallas_call`` on exit."""
+    list; restores the real ``pallas_call`` on exit.
+
+    The kernel launchers keep one trace per signature, so their caches
+    are cleared on entry (a launcher traced before would never reach the
+    recorder) and on exit (no later call may reuse a recorded trace)."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+
+    from ..kernels.common import clear_launcher_caches
 
     records: list[KernelCapture] = []
     real = pl.pallas_call
@@ -122,11 +128,13 @@ def capture_pallas_calls() -> Iterator[list[KernelCapture]]:
 
         return runner
 
+    clear_launcher_caches()
     pl.pallas_call = fake_pallas_call
     try:
         yield records
     finally:
         pl.pallas_call = real
+        clear_launcher_caches()
 
 
 def _iter_grid(grid: tuple[int, ...]) -> Iterator[tuple[int, ...]]:

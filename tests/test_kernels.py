@@ -7,6 +7,12 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+import repro
+from repro.engine.execute import mttkrp
+from repro.kernels.common import clear_launcher_caches
+from repro.kernels.mttkrp3 import mttkrp3_pallas
+from repro.kernels.mttkrpn import mttkrp_partial_pallas, mttkrpn_pallas
+from repro.kernels.multi_ttm import multi_ttm_keep_pallas
 from repro.kernels.ops import (
     VMEM_BUDGET,
     BlockPlan,
@@ -15,7 +21,8 @@ from repro.kernels.ops import (
     mttkrp_traffic_model,
 )
 from repro.kernels.ref import mttkrp_ref
-from repro.observe.metrics import TENSOR_RELAYOUTS, registry
+from repro.kernels.sweep import mttkrp_fused_pair_pallas
+from repro.observe.metrics import LAUNCHER_TRACES, TENSOR_RELAYOUTS, registry
 
 # interpret-mode kernel sweeps dominate the suite's wall time
 pytestmark = pytest.mark.slow
@@ -192,3 +199,115 @@ def test_kernel_jit_compatible():
 
     out = f(x, fs[1], fs[2])
     np.testing.assert_allclose(out, mttkrp_ref(x, fs, 0), rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# Each launcher is traced, lowered and compiled once per static signature
+# ---------------------------------------------------------------------------
+
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+LAUNCHERS = [
+    "mttkrp3-0", "mttkrp3-1", "mttkrp3-2",
+    "mttkrpn", "mttkrp_partial", "multi_ttm", "sweep",
+]
+
+
+@pytest.fixture
+def lowerings():
+    """How many programs JAX has lowered since the test started."""
+    seen = []
+
+    def listen(event, secs, **kwargs):
+        if event == LOWERING_EVENT:
+            seen.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    yield lambda: len(seen)
+    jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def _traces(before) -> float:
+    return registry().delta(before).get(LAUNCHER_TRACES, 0)
+
+
+def _launch(name, rows, block_i):
+    """``(launcher, operands, static keywords)`` of one pre-padded launch
+    with ``rows`` output rows tiled ``block_i`` at a time."""
+    def rnd(*shape):
+        return jax.random.normal(jax.random.PRNGKey(sum(shape)), shape)
+
+    if name.startswith("mttkrp3"):
+        mode = int(name[-1])
+        shape, blocks = [8, 8, 8], [8, 8, 8]
+        shape[mode], blocks[mode] = rows, block_i
+        fs = [rnd(shape[k], 4) for k in range(3) if k != mode]
+        return mttkrp3_pallas, (rnd(*shape), fs, mode), dict(
+            blocks=blocks, block_r=4
+        )
+    tiles = dict(block_i=block_i, block_contract=(4, 4), block_r=4)
+    if name == "mttkrpn":
+        tiles["block_contract"] = (4, 4, 4)
+        return mttkrpn_pallas, (rnd(rows, 4, 4, 4), [rnd(4, 4)] * 3), tiles
+    if name == "mttkrp_partial":
+        return mttkrp_partial_pallas, (
+            rnd(rows, 4, 4, 4), [rnd(4, 4)] * 2
+        ), tiles
+    if name == "multi_ttm":
+        del tiles["block_r"]
+        return multi_ttm_keep_pallas, (
+            rnd(rows, 4, 4), [rnd(4, 2), rnd(4, 3)]
+        ), tiles
+    return mttkrp_fused_pair_pallas, (rnd(rows, 4, 4), [rnd(4, 4)] * 2), tiles
+
+
+@pytest.mark.parametrize("name", LAUNCHERS)
+def test_launcher_built_once_per_signature(name, lowerings):
+    """A second eager call at one signature lowers nothing and returns
+    what the first did; a new shape, then new blocks, trace the launcher
+    once more each."""
+    clear_launcher_caches()
+    fn, args, kw = _launch(name, 8, 8)
+    before = registry().snapshot()
+    start = lowerings()
+    first = jax.block_until_ready(fn(*args, interpret=True, **kw))
+    assert lowerings() > start
+    assert _traces(before) == 1
+    lowered = lowerings()
+    second = fn(*args, interpret=True, **kw)
+    assert lowerings() == lowered
+    assert _traces(before) == 1
+    jax.tree.map(np.testing.assert_array_equal, first, second)
+    for want, (rows, block_i) in ((2, (16, 8)), (3, (8, 4))):
+        fn, args, kw = _launch(name, rows, block_i)
+        fn(*args, interpret=True, **kw)
+        assert _traces(before) == want
+
+
+def test_batched_launch_built_once(lowerings):
+    """The served path's shape: the engine's eager ``vmap`` over a
+    leading batch axis reuses the batched launch program."""
+    ctx = repro.ExecutionContext.create(backend="pallas", interpret=True)
+    x, fs = _mk((3, 8, 8, 8), 4, seed=11)
+    fs = fs[1:]
+    clear_launcher_caches()
+    first = jax.block_until_ready(mttkrp(x, fs, 1, ctx=ctx))
+    lowered = lowerings()
+    before = registry().snapshot()
+    second = mttkrp(x, fs, 1, ctx=ctx)
+    assert lowerings() == lowered
+    assert _traces(before) == 0
+    np.testing.assert_array_equal(first, second)
+    want = jnp.stack([mttkrp_ref(x[b], fs, 1) for b in range(3)])
+    np.testing.assert_allclose(second, want, rtol=2e-4, atol=2e-4)
+
+
+def test_launcher_traces_count_one_per_signature():
+    """``kernels.launcher_traces`` counts the launcher's traces: N eager
+    calls at one signature count 1."""
+    x, fs = _mk((8, 16, 8), 4, seed=12)
+    clear_launcher_caches()
+    before = registry().snapshot()
+    for _ in range(5):
+        mttkrp_pallas(x, fs, 1, interpret=True)
+    assert _traces(before) == 1

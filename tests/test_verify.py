@@ -23,6 +23,10 @@ Five sections mirror the five analyzers:
   ``narrow-accumulator``.
 """
 
+import jax
+import jax.numpy as jnp
+import numpy as np
+
 from repro.engine.plan import BlockPlan, Memory, MultiTTMPlan
 from repro.observe.metrics import PALLAS_DISPATCHES
 from repro.observe import load_trace, registry
@@ -46,6 +50,7 @@ from repro.verify.kernels import (
     KernelCapture,
     SpecCapture,
     check_capture,
+    kernel_cases,
     verify_kernels,
 )
 from repro.verify.lint import RULES, lint_source, lint_tree, rule_catalog
@@ -225,6 +230,26 @@ def test_shipped_kernels_verify_clean_without_executing():
         # accumulation runs, not single-block trivia
         assert len([g for g in v["grid"] if g > 1]) >= 2, v
     assert registry().counter(PALLAS_DISPATCHES) == before
+
+
+def test_verify_kernels_captures_after_launchers_are_warm():
+    """A launcher traced before at a capture's own signature still
+    reaches the recorder (its cache is cleared on entry), and a launch
+    after the capture runs the real kernel again, not the recorded
+    zeros (cleared on exit)."""
+    def run(case):
+        return case["fn"](*(
+            jnp.ones(a.shape, a.dtype) for a in case["args"]
+        ))
+
+    warm = {case["name"]: run(case) for case in kernel_cases()}
+    findings, verdicts = verify_kernels()
+    assert findings == []
+    assert {v["name"] for v in verdicts} == set(warm)
+    for case in kernel_cases():
+        again = run(case)
+        jax.tree.map(np.testing.assert_array_equal, again, warm[case["name"]])
+        assert all(bool(jnp.any(a != 0)) for a in jax.tree.leaves(again))
 
 
 # ---------------------------------------------------------------------------
