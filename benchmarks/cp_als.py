@@ -46,7 +46,7 @@ def _time_als(x, rank, tree: bool) -> tuple[float, float]:
     t0 = time.perf_counter()
     res = cp_als(
         x, rank, n_iters=5, key=jax.random.PRNGKey(1),
-        use_dimension_tree=tree,
+        sweep="dimtree" if tree else "per_mode",
     )
     jax.block_until_ready(res.factors[0])
     return (time.perf_counter() - t0) / 5, res.final_fit

@@ -17,9 +17,8 @@ so the full tensor is reconstructed only implicitly.
 
 Configuration: both drivers take ``ctx: ExecutionContext`` — one object
 carrying backend/memory/interpret/tune and the Distribution sub-config
-(mesh/grid/procs). The legacy kwargs still work for one release through
-the deprecation shim; all option validation (backend names, tune x
-distributed, mttkrp_fn x distributed, ...) lives in
+(mesh/grid/procs); without one, the process default. Option validation
+(backend names, tune x distributed, ...) lives in
 :mod:`repro.engine.context`, not here.
 """
 
@@ -90,19 +89,10 @@ def cp_als(
     key: jax.Array | None = None,
     init_factors: Sequence[jax.Array] | None = None,
     mttkrp_fn: MttkrpFn | None = None,
-    use_dimension_tree: bool = False,
-    tol: float = 0.0,
     *,
-    sweep: str | None = None,
+    tol: float = 0.0,
+    sweep: str = "per_mode",
     ctx: "ExecutionContext | None" = None,
-    backend=None,
-    memory=None,
-    interpret=None,
-    tune=None,
-    distributed=None,
-    mesh=None,
-    grid=None,
-    procs=None,
 ) -> CPResult:
     """CP-ALS. One sweep = for each mode n: B = MTTKRP; solve the normal
     equations A_n = B (Γ_n)^+; column-normalize into weights λ.
@@ -116,59 +106,42 @@ def cp_als(
     plain path.
 
     ``sweep`` selects the sweep schedule: ``"per_mode"`` (the plain N-pass
-    Gauss-Seidel chain), ``"dimtree"`` (binary dimension-tree reuse, same
-    as ``use_dimension_tree=True``), ``"fused"`` (the arXiv:1708.08976
+    Gauss-Seidel chain, the default), ``"dimtree"`` (binary
+    dimension-tree reuse), ``"fused"`` (the arXiv:1708.08976
     mode-reuse schedule — 2 tensor passes per sweep, single-dispatch
     (B0, P') pair on the pallas backend; see
     :func:`repro.engine.sweep.fused_als_sweep`), or ``"auto"`` (resolve
     fused-vs-per-mode through the tune cache under ``kind="sweep"`` keys;
     ``ctx.tune`` measures both on the first call and persists the
-    winner). All schedules are Gauss-Seidel exact. Default: derived from
-    ``use_dimension_tree``.
+    winner). All schedules are Gauss-Seidel exact.
 
-    ``ctx.distribution`` (or the legacy ``distributed=True`` /
-    ``mesh``/``grid``/``procs`` kwargs) runs the stationary-tensor sweep
-    driver instead
+    ``ctx.distribution`` runs the stationary-tensor sweep driver instead
     (:func:`repro.distributed.cp_als_parallel.cp_als_parallel`): X is
     block-distributed over an automatically selected Eq (12)-optimal
     processor grid and each sweep is one shard_map program whose local
-    MTTKRPs still go through the engine backend."""
-    from ..engine.context import (
-        UNSET,
-        check_driver_options,
-        context_from_legacy,
-    )
+    MTTKRPs still go through the engine backend. Only ``sweep="per_mode"``
+    runs there, and ``mttkrp_fn`` cannot be combined with it."""
+    from ..engine.context import ExecutionContext
 
-    legacy = {
-        "backend": backend, "memory": memory, "interpret": interpret,
-        "tune": tune, "distributed": distributed, "mesh": mesh,
-        "grid": grid, "procs": procs,
-    }
-    ctx = context_from_legacy(
-        "repro.cp_als", ctx,
-        {k: (UNSET if v is None else v) for k, v in legacy.items()},
-    )
-    check_driver_options(
-        ctx, mttkrp_fn=mttkrp_fn, use_dimension_tree=use_dimension_tree
-    )
-    if sweep is not None:
-        if sweep not in ("per_mode", "dimtree", "fused", "auto"):
+    ctx = ctx if ctx is not None else ExecutionContext.default()
+    if sweep not in ("per_mode", "dimtree", "fused", "auto"):
+        raise ValueError(
+            f"unknown sweep {sweep!r}; expected 'per_mode', 'dimtree', "
+            f"'fused', or 'auto'"
+        )
+    if ctx.is_distributed:
+        if mttkrp_fn is not None:
             raise ValueError(
-                f"unknown sweep {sweep!r}; expected 'per_mode', 'dimtree', "
-                f"'fused', or 'auto'"
+                "mttkrp_fn cannot be combined with the distributed path "
+                "(the sweep driver owns the collectives); drop mttkrp_fn "
+                "or the context's distribution"
             )
-        if use_dimension_tree and sweep != "dimtree":
-            raise ValueError(
-                f"sweep={sweep!r} conflicts with use_dimension_tree=True "
-                f"(pass only one of the two)"
-            )
-        if ctx.is_distributed and sweep != "per_mode":
+        if sweep != "per_mode":
             raise ValueError(
                 f"sweep={sweep!r} is not supported on the distributed path "
                 f"(the stationary sweep already amortizes factor gathers; "
                 f"overlap='ring' is its comm/compute-overlap knob)"
             )
-    if ctx.is_distributed:
         from ..distributed.cp_als_parallel import cp_als_parallel
 
         return cp_als_parallel(
@@ -177,13 +150,9 @@ def cp_als(
         )
     from ..observe import trace as _otrace
 
-    schedule = sweep if sweep is not None else (
-        "dimtree" if use_dimension_tree else "per_mode"
-    )
     with _otrace.annotated("repro.cp_als"):
         return _cp_als_local(
-            x, rank, n_iters, key, init_factors, mttkrp_fn, tol, schedule,
-            ctx,
+            x, rank, n_iters, key, init_factors, mttkrp_fn, tol, sweep, ctx,
         )
 
 
@@ -298,10 +267,6 @@ def cp_gradient(
     mttkrp_fn: MttkrpFn | None = None,
     *,
     ctx: "ExecutionContext | None" = None,
-    backend=None,
-    memory=None,
-    interpret=None,
-    tune=None,
 ) -> CPResult:
     """Gradient-based CP (Adam on the analytic MTTKRP gradient).
 
@@ -309,16 +274,6 @@ def cp_gradient(
     ``engine.execute.mttkrp`` under the same ``ctx``
     (backend/memory/interpret/tune). An explicit ``mttkrp_fn`` still
     overrides."""
-    from ..engine.context import UNSET, context_from_legacy
-
-    legacy = {
-        "backend": backend, "memory": memory, "interpret": interpret,
-        "tune": tune,
-    }
-    ctx = context_from_legacy(
-        "repro.cp_gradient", ctx,
-        {k: (UNSET if v is None else v) for k, v in legacy.items()},
-    )
     n = x.ndim
     if mttkrp_fn is None:
         from ..engine import execute as engine_execute

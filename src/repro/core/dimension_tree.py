@@ -29,9 +29,6 @@ def all_mode_mttkrp_dimtree(
     factors: Sequence[jax.Array],
     *,
     ctx: "ExecutionContext | None" = None,
-    backend=None,
-    memory=None,
-    interpret=None,
 ) -> list[jax.Array]:
     """All-mode MTTKRP via a binary dimension tree.
 
@@ -40,17 +37,8 @@ def all_mode_mttkrp_dimtree(
     N=3,4 and asymptotically fewer for larger N. ``ctx.backend='pallas'``
     runs every partial contraction on the blocked kernels.
     """
-    from ..engine.context import UNSET, context_from_legacy
     from ..engine.tree import all_mode_mttkrp
 
-    ctx = context_from_legacy(
-        "repro.core.all_mode_mttkrp_dimtree", ctx,
-        {
-            "backend": backend if backend is not None else UNSET,
-            "memory": memory if memory is not None else UNSET,
-            "interpret": interpret if interpret is not None else UNSET,
-        },
-    )
     return all_mode_mttkrp(x, factors, method="dimtree", ctx=ctx)
 
 
@@ -60,24 +48,12 @@ def dimtree_als_sweep(
     update_fn,
     *,
     ctx: "ExecutionContext | None" = None,
-    backend=None,
-    memory=None,
-    interpret=None,
 ) -> None:
     """One ALS sweep with dimension-tree reuse, *exactly* matching the
     Gauss-Seidel order of plain ALS (see :mod:`repro.engine.tree` for the
     ordering argument). ``factors`` is updated in place."""
-    from ..engine.context import UNSET, context_from_legacy
     from ..engine.tree import dimtree_als_sweep as engine_sweep
 
-    ctx = context_from_legacy(
-        "repro.core.dimtree_als_sweep", ctx,
-        {
-            "backend": backend if backend is not None else UNSET,
-            "memory": memory if memory is not None else UNSET,
-            "interpret": interpret if interpret is not None else UNSET,
-        },
-    )
     engine_sweep(x, factors, update_fn, ctx=ctx)
 
 

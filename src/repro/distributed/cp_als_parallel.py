@@ -218,32 +218,22 @@ def build_cp_sweep(
     ndim: int,
     *,
     ctx=None,
-    backend=None,
-    interpret=None,
-    memory=None,
     local_fn: LocalFn | None = None,
     compute_fit: bool = True,
 ) -> Callable:
     """Compile-ready sweep: ``f(x, factors, blocks, grams, normx) ->
     (factors, blocks, grams, weights, fit)`` with every operand in the
     carried distributed state layout (see :func:`place_cp_state`)."""
-    from ..engine.context import UNSET, context_from_legacy
+    from ..engine.context import ExecutionContext
 
-    ctx = context_from_legacy(
-        "repro.distributed.build_cp_sweep", ctx,
-        {
-            "backend": backend if backend is not None else UNSET,
-            "interpret": interpret if interpret is not None else UNSET,
-            "memory": memory if memory is not None else UNSET,
-        },
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     if RANK_AXIS in mesh.axis_names:
         raise ValueError(
             "the CP-ALS sweep keeps X stationary (Algorithm 3); rank-axis "
             "(p0>1) meshes are for single-mode mttkrp_general"
         )
     if local_fn is None:
-        local_fn = engine_local_fn(ctx)
+        local_fn = engine_local_fn(ctx=ctx)
     overlap = (
         ctx.distribution.overlap if ctx.distribution is not None else "none"
     )
@@ -371,19 +361,12 @@ def cp_als_parallel(
     key: jax.Array | None = None,
     init_factors: Sequence[jax.Array] | None = None,
     ctx=None,
-    grid: Sequence[int] | None = None,
-    mesh: jax.sharding.Mesh | None = None,
-    procs: int | None = None,
-    backend=None,
-    interpret=None,
-    memory=None,
     tol: float = 0.0,
     compute_fit: bool = True,
 ) -> CPResult:
     """Distributed CP-ALS with automatic grid selection.
 
-    Grid resolution (all read from ``ctx.distribution``; the legacy
-    ``grid``/``mesh``/``procs`` kwargs shim into one) is
+    Grid resolution (all read from ``ctx.distribution``) is
     :func:`resolve_cp_mesh`'s.  An X already in the sweep's layout
     (``ctx.tensor_sharding(x.shape, rank)``) is used in place; any other
     X is block-distributed first.  Factors are
@@ -399,20 +382,10 @@ def cp_als_parallel(
     ``distributed.modeled_collective_bytes`` (the Eq (12) sweep model,
     :func:`stationary_sweep_words` times the itemsize, per sweep).
     """
-    from ..engine.context import UNSET, context_from_legacy
+    from ..engine.context import ExecutionContext
     from ..observe import trace as _otrace
 
-    ctx = context_from_legacy(
-        "repro.distributed.cp_als_parallel", ctx,
-        {
-            "backend": backend if backend is not None else UNSET,
-            "interpret": interpret if interpret is not None else UNSET,
-            "memory": memory if memory is not None else UNSET,
-            "grid": grid if grid is not None else UNSET,
-            "mesh": mesh if mesh is not None else UNSET,
-            "procs": procs if procs is not None else UNSET,
-        },
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     with _otrace.annotated("repro.cp_als_parallel"):
         return _cp_als_parallel(
             x, rank, n_iters, key, init_factors, ctx, tol,

@@ -36,12 +36,7 @@ from .mesh import RANK_AXIS, hyperslice_axes, mode_axis, row_sharding_axes
 LocalFn = Callable[[jax.Array, Sequence[jax.Array], int], jax.Array]
 
 
-def engine_local_fn(
-    ctx=None,
-    interpret=None,
-    memory=None,
-    backend=None,
-) -> LocalFn:
+def engine_local_fn(*, ctx=None) -> LocalFn:
     """Per-processor MTTKRP through the engine's dispatch layer.
 
     This is the paper's separation of concerns made literal: Algorithms 3/4
@@ -55,42 +50,12 @@ def engine_local_fn(
 
     ``ctx`` is an :class:`~repro.engine.context.ExecutionContext` (its
     ``local()`` view is used — the collectives here are owned by the
-    algorithms, not the engine). A legacy backend *string* first argument
-    still works through the deprecation shim.
+    algorithms, not the engine); without one, the process default.
     """
     from ..engine import execute as engine_execute  # call-time: layer cycle
-    from ..engine.context import UNSET, context_from_legacy
+    from ..engine.context import ExecutionContext
 
-    if isinstance(ctx, str):  # old positional form: engine_local_fn("pallas")
-        if backend is not None:
-            raise TypeError(
-                "repro.distributed.engine_local_fn: backend given both "
-                "positionally and by keyword"
-            )
-        ctx, backend = None, ctx
-    if ctx is None and (
-        backend is not None or interpret is not None or memory is not None
-    ):
-        ctx = context_from_legacy(
-            "repro.distributed.engine_local_fn", None,
-            {
-                "backend": backend if backend is not None else UNSET,
-                "interpret": interpret if interpret is not None else UNSET,
-                "memory": memory if memory is not None else UNSET,
-            },
-        )
-    elif ctx is not None and (
-        backend is not None or interpret is not None or memory is not None
-    ):
-        raise TypeError(
-            "repro.distributed.engine_local_fn: pass either ctx= or the "
-            "legacy keyword arguments (backend, interpret, memory), not "
-            "both — the context already carries the full configuration"
-        )
-    elif ctx is None:
-        from ..engine.context import ExecutionContext
-
-        ctx = ExecutionContext.default()
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     local_ctx = ctx.local()
 
     def fn(x, factors, mode):
@@ -176,18 +141,15 @@ def _stationary_local(
     )
 
 
-def _resolve_parallel_ctx(api: str, ctx, backend, interpret):
-    """Shared ctx/legacy resolution for the Alg 3/4 builders, plus the
-    replication-check policy: a ``pallas_call`` output carries no
-    manual-axes type, so the (purely diagnostic) check is skipped when
+def _resolve_parallel_ctx(ctx):
+    """The Alg 3/4 builders' context (the process default without one)
+    and their replication-check policy: a ``pallas_call`` output carries
+    no manual-axes type, so the (purely diagnostic) check is skipped when
     the local body may contain a kernel ("auto" can resolve to pallas at
     trace time)."""
-    from ..engine.context import context_from_legacy
+    from ..engine.context import ExecutionContext
 
-    ctx = context_from_legacy(
-        api, ctx, {"backend": backend, "interpret": interpret},
-        stacklevel=4,
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     return ctx, ctx.backend not in ("pallas", "auto")
 
 
@@ -198,8 +160,6 @@ def mttkrp_stationary(
     local_fn: LocalFn | None = None,
     *,
     ctx=None,
-    backend=None,
-    interpret=None,
 ):
     """Build the Alg-3 shard_map callable ``f(x, *factors_except_mode)``.
 
@@ -208,15 +168,9 @@ def mttkrp_stationary(
     local MTTKRP goes through the engine under ``ctx`` (the backend selects
     einsum / blocked_host / pallas); an explicit ``local_fn`` overrides it.
     """
-    from ..engine.context import UNSET
-
-    ctx, check_rep = _resolve_parallel_ctx(
-        "repro.distributed.mttkrp_stationary", ctx,
-        backend if backend is not None else UNSET,
-        interpret if interpret is not None else UNSET,
-    )
+    ctx, check_rep = _resolve_parallel_ctx(ctx)
     if local_fn is None:
-        local_fn = engine_local_fn(ctx)
+        local_fn = engine_local_fn(ctx=ctx)
     in_specs = (tensor_spec(ndim),) + tuple(
         factor_spec(ndim, k) for k in range(ndim) if k != mode
     )
@@ -277,8 +231,6 @@ def mttkrp_general(
     local_fn: LocalFn | None = None,
     *,
     ctx=None,
-    backend=None,
-    interpret=None,
 ):
     """Build the Alg-4 shard_map callable ``f(x, *factors_except_mode)``.
 
@@ -286,15 +238,9 @@ def mttkrp_general(
     Alg 3 is the special case p0 == 1 (the 'r' collectives degenerate).
     The local MTTKRP goes through the engine like :func:`mttkrp_stationary`.
     """
-    from ..engine.context import UNSET
-
-    ctx, check_rep = _resolve_parallel_ctx(
-        "repro.distributed.mttkrp_general", ctx,
-        backend if backend is not None else UNSET,
-        interpret if interpret is not None else UNSET,
-    )
+    ctx, check_rep = _resolve_parallel_ctx(ctx)
     if local_fn is None:
-        local_fn = engine_local_fn(ctx)
+        local_fn = engine_local_fn(ctx=ctx)
     in_specs = (tensor_spec(ndim, rank_split_mode=0),) + tuple(
         factor_spec(ndim, k, rank_axis=True)
         for k in range(ndim)

@@ -10,7 +10,7 @@ Layering (see docs/ARCHITECTURE.md):
 
     plan      — Memory descriptors, BlockPlan, choose_blocks, Eq 9/10 models
     context   — ExecutionContext: the one immutable config object + the
-                validation catalog + the deprecated-kwarg shim
+                validation catalog
     execute   — mttkrp(x, factors, mode, ctx=...) + partial contractions
                 (a leading batch axis vmaps B problems over ONE plan)
     batch     — cp_als_batched / tucker_hooi_batched: B decompositions
@@ -25,7 +25,6 @@ from .context import (
     PlanDecision,
     ProblemSpec,
     check_backend,
-    check_driver_options,
 )
 from .plan import (
     LANE,
@@ -59,7 +58,6 @@ __all__ = [
     "PlanDecision",
     "ProblemSpec",
     "check_backend",
-    "check_driver_options",
     "LANE",
     "SUBLANE",
     "VMEM_BUDGET",

@@ -17,10 +17,9 @@ error messages.  This module replaces all of that:
   ``overlap``).  Built once, validated once (eagerly, in
   ``__post_init__`` — so every construction path validates), consumed
   everywhere.
-* :meth:`ExecutionContext.create` — the single constructor every driver's
-  deprecated-kwarg shim routes through; *all* option validation lives
-  here (one error-message catalog, see :func:`check_backend` and
-  friends).
+* :meth:`ExecutionContext.create` — the single constructor from flat
+  options; *all* option validation lives here (one error-message
+  catalog, see :func:`check_backend` and friends).
 * :meth:`ExecutionContext.for_problem` — eager ``"auto"`` resolution:
   the processor grid is selected once (via
   :func:`repro.distributed.grid_select.choose_cp_grid`) and the per-mode
@@ -42,8 +41,7 @@ from __future__ import annotations
 
 import json
 import os
-import warnings
-from dataclasses import dataclass, field, fields, replace
+from dataclasses import dataclass, field, replace
 from pathlib import Path
 from typing import Any, Mapping, Sequence
 
@@ -54,24 +52,6 @@ ENV_CONTEXT = "REPRO_CONTEXT"
 
 #: Concrete executors plus the autotuner-resolved pseudo-backend.
 VALID_BACKENDS = ("einsum", "blocked_host", "pallas", "auto")
-
-
-class _Unset:
-    """Sentinel distinguishing 'kwarg not passed' from an explicit value
-    (needed so the deprecation shims only fire on actual legacy usage)."""
-
-    _instance = None
-
-    def __new__(cls):
-        if cls._instance is None:
-            cls._instance = super().__new__(cls)
-        return cls._instance
-
-    def __repr__(self):  # pragma: no cover - debug aid
-        return "<unset>"
-
-
-UNSET = _Unset()
 
 
 # ---------------------------------------------------------------------------
@@ -98,38 +78,6 @@ def _err_tune_distributed() -> ValueError:
         "tune=True)), then run distributed with backend='auto' to "
         "replay the cache"
     )
-
-
-def _err_mttkrp_fn_distributed() -> ValueError:
-    return ValueError(
-        "mttkrp_fn cannot be combined with the distributed path "
-        "(the sweep driver owns the collectives); drop mttkrp_fn or the "
-        "distributed options (distributed/mesh/grid/procs)"
-    )
-
-
-def _err_dimtree_distributed() -> ValueError:
-    return ValueError(
-        "use_dimension_tree is not supported with distributed=True "
-        "(the stationary sweep already amortizes factor gathers across "
-        "all modes); drop one of the two options"
-    )
-
-
-def check_driver_options(
-    ctx: "ExecutionContext",
-    *,
-    mttkrp_fn: Any = None,
-    use_dimension_tree: bool = False,
-) -> None:
-    """Validate per-call driver arguments that are not part of the context
-    (callables cannot be frozen/serialized) against it — the CP drivers'
-    entire option validation, unified."""
-    if ctx.is_distributed:
-        if mttkrp_fn is not None:
-            raise _err_mttkrp_fn_distributed()
-        if use_dimension_tree:
-            raise _err_dimtree_distributed()
 
 
 # ---------------------------------------------------------------------------
@@ -879,9 +827,9 @@ class ExecutionContext:
 
     @classmethod
     def default(cls) -> "ExecutionContext":
-        """What a driver uses when handed neither ``ctx`` nor legacy
-        kwargs: the ``REPRO_CONTEXT`` seed if set, else the stock einsum
-        context. Memoized on the raw env value — bare driver calls in
+        """What a driver uses when handed no ``ctx``: the
+        ``REPRO_CONTEXT`` seed if set, else the stock einsum context.
+        Memoized on the raw env value — bare driver calls in
         hot loops must not re-read files or re-parse JSON."""
         raw = os.environ.get(ENV_CONTEXT) or ""
         cached = _DEFAULT_MEMO.get(raw)
@@ -892,56 +840,5 @@ class ExecutionContext:
         return cached
 
 
-# ---------------------------------------------------------------------------
-# The deprecated-kwarg shim (one release of backward compatibility)
-# ---------------------------------------------------------------------------
-
 # memo for ExecutionContext.default(), keyed by the raw REPRO_CONTEXT value
 _DEFAULT_MEMO: dict[str, "ExecutionContext"] = {}
-
-_CREATE_KEYS = (
-    {f.name for f in fields(ExecutionContext)}
-    | {"distributed", "mesh", "grid", "procs", "p0", "overlap"}
-) - {"distribution", "problem", "decisions"}
-
-
-def context_from_legacy(
-    api: str,
-    ctx: "ExecutionContext | None",
-    legacy: Mapping[str, Any],
-    *,
-    stacklevel: int = 3,
-) -> "ExecutionContext":
-    """Resolve one driver call's configuration: ``ctx`` if given, else a
-    context built from the legacy kwargs (with exactly one
-    :class:`DeprecationWarning` naming the new spelling), else the
-    process default.
-
-    ``legacy`` maps old kwarg names to values, with :data:`UNSET` marking
-    kwargs the caller did not pass — only actually-passed kwargs trigger
-    the warning, so ``mttkrp(x, factors, mode)`` stays silent.
-    """
-    used = {k: v for k, v in legacy.items() if v is not UNSET}
-    if ctx is not None:
-        if used:
-            raise TypeError(
-                f"{api}: pass either ctx= or the legacy keyword arguments "
-                f"({', '.join(sorted(used))}), not both — the context "
-                f"already carries the full configuration"
-            )
-        return ctx
-    if not used:
-        return ExecutionContext.default()
-    unknown = set(used) - _CREATE_KEYS
-    if unknown:  # pragma: no cover - shims only forward known keys
-        raise TypeError(f"{api}: unknown options {sorted(unknown)}")
-    warnings.warn(
-        f"{api}: passing execution options as keyword arguments "
-        f"({', '.join(sorted(used))}) is deprecated and will be removed "
-        f"in the next release; build one ExecutionContext instead — "
-        f"ctx = repro.ExecutionContext.create(...) and call "
-        f"{api}(..., ctx=ctx)",
-        DeprecationWarning,
-        stacklevel=stacklevel,
-    )
-    return ExecutionContext.create(**used)

@@ -15,12 +15,10 @@ Backends
                     miss and persists the winner.
 
 Configuration comes in as ONE :class:`~repro.engine.context.ExecutionContext`
-(``ctx=``): backend, Memory, dtype policy, interpret, tuning policy. The
-legacy per-call kwargs (``backend=``/``memory=``/``interpret=``/``tune=``)
-still work for one release through the deprecation shim, which builds a
-context and warns. Per-problem *overrides* (``plan``, ``block``,
-``kernel_variant``, ``out_dtype``) stay explicit arguments: they pin one
-contraction's execution details, not the machine.
+(``ctx=``): backend, Memory, dtype policy, interpret, tuning policy;
+without one, the process default. Per-problem *overrides* (``plan``,
+``block``, ``kernel_variant``, ``out_dtype``) stay explicit arguments:
+they pin one contraction's execution details, not the machine.
 
 :func:`contract_partial` is the engine's generalized contraction: any
 dimension-tree node (tensor x a subset of factors, optionally carrying the
@@ -52,12 +50,7 @@ from ..core.blocked import mttkrp_blocked
 from ..core.mttkrp import mttkrp as _einsum_mttkrp
 from ..observe import trace as _otrace
 from ..observe.metrics import PALLAS_DISPATCHES, registry
-from .context import (
-    UNSET,
-    ExecutionContext,
-    check_backend,
-    context_from_legacy,
-)
+from .context import ExecutionContext, check_backend
 from .plan import (
     BlockPlan,
     Memory,
@@ -138,10 +131,6 @@ def mttkrp(
     block: int | None = None,
     out_dtype=None,
     kernel_variant: str | None = None,
-    backend=UNSET,
-    memory=UNSET,
-    interpret=UNSET,
-    tune=UNSET,
 ) -> jax.Array:
     """MTTKRP through the engine: ``B^(mode)(i, r)``.
 
@@ -160,11 +149,7 @@ def mttkrp(
     under tracing, where nothing can be timed — resolution itself is
     trace-safe).
     """
-    ctx = context_from_legacy(
-        "repro.mttkrp", ctx,
-        {"backend": backend, "memory": memory, "interpret": interpret,
-         "tune": tune},
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     if x.ndim == len(factors) + 1:
         # leading batch axis: B independent MTTKRPs under ONE resolved plan
         return _mttkrp_batched(
@@ -417,10 +402,6 @@ def contract_partial(
     *,
     ctx: ExecutionContext | None = None,
     plan: BlockPlan | None = None,
-    backend=UNSET,
-    memory=UNSET,
-    interpret=UNSET,
-    tune=UNSET,
 ) -> jax.Array:
     """Contract the factors for ``drop`` out of a dimension-tree ``node``.
 
@@ -441,11 +422,7 @@ def contract_partial(
     tracing — resolution itself is trace-safe, so dimension-tree sweeps
     inside jit still work).
     """
-    ctx = context_from_legacy(
-        "repro.contract_partial", ctx,
-        {"backend": backend, "memory": memory, "interpret": interpret,
-         "tune": tune},
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     if node.ndim == len(modes) + int(has_rank) + 1:
         # leading batch axis: B tree-node contractions under ONE plan
         return _contract_partial_batched(

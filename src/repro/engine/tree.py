@@ -12,8 +12,7 @@ Khatri-Rao structure), so each one is planned and dispatched through
 :class:`~repro.engine.context.ExecutionContext` — with
 ``ctx.backend == 'pallas'`` the whole sweep runs on the blocked VMEM/MXU
 kernels instead of einsum, with the same blocking discipline per partial
-contraction. The legacy ``backend=/memory=/interpret=/tune=`` kwargs
-route through the deprecation shim.
+contraction.
 """
 
 from __future__ import annotations
@@ -23,7 +22,7 @@ from typing import Callable, Dict, Sequence
 import jax
 
 from ..observe import trace as _otrace
-from .context import UNSET, ExecutionContext, context_from_legacy
+from .context import ExecutionContext
 from .execute import contract_partial, mttkrp
 
 
@@ -67,10 +66,6 @@ def all_mode_mttkrp(
     *,
     method: str = "dimtree",
     ctx: ExecutionContext | None = None,
-    backend=UNSET,
-    memory=UNSET,
-    interpret=UNSET,
-    tune=UNSET,
 ) -> list[jax.Array]:
     """MTTKRP in every mode: ``[B^(0), ..., B^(N-1)]``.
 
@@ -81,11 +76,7 @@ def all_mode_mttkrp(
     ``ctx.backend == "auto"`` resolves every edge through the autotuner's
     plan cache (see :mod:`repro.tune`).
     """
-    ctx = context_from_legacy(
-        "repro.engine.tree.all_mode_mttkrp", ctx,
-        {"backend": backend, "memory": memory, "interpret": interpret,
-         "tune": tune},
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
     n = x.ndim
     if method == "independent":
         return [mttkrp(x, factors, m, ctx=ctx) for m in range(n)]
@@ -115,10 +106,6 @@ def dimtree_als_sweep(
     update_fn: Callable[[int, jax.Array], jax.Array],
     *,
     ctx: ExecutionContext | None = None,
-    backend=UNSET,
-    memory=UNSET,
-    interpret=UNSET,
-    tune=UNSET,
 ) -> None:
     """One ALS sweep with dimension-tree reuse, *exactly* matching the
     Gauss-Seidel order of plain ALS.
@@ -128,11 +115,7 @@ def dimtree_als_sweep(
     ordering argument), must return the new factor, and may maintain its
     own side state (grams, weights). ``factors`` is updated in place.
     """
-    ctx = context_from_legacy(
-        "repro.engine.tree.dimtree_als_sweep", ctx,
-        {"backend": backend, "memory": memory, "interpret": interpret,
-         "tune": tune},
-    )
+    ctx = ctx if ctx is not None else ExecutionContext.default()
 
     def leaf(mode: int, b: jax.Array) -> None:
         factors[mode] = update_fn(mode, b)

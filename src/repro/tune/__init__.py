@@ -19,10 +19,10 @@ with empirical tuning). This package closes it in three parts:
                 ``BlockPlan.traffic_model`` predictions can be scored
                 against measurements (model-vs-measured error report).
 
-``engine.execute.mttkrp(..., backend="auto")`` resolves through
-:func:`repro.tune.search.resolve`: cache hit → the tuned plan, exactly as
-persisted; miss → the analytic model-best plan (plus ``tune=True`` to
-search empirically and persist the winner).
+``engine.execute.mttkrp`` under a ``backend="auto"`` context resolves
+through :func:`repro.tune.search.resolve`: cache hit → the tuned plan,
+exactly as persisted; miss → the analytic model-best plan (plus
+``tune=True`` to search empirically and persist the winner).
 """
 
 from .cache import (

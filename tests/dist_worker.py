@@ -40,6 +40,7 @@ from repro.distributed.compression import (  # noqa: E402
     cp_compressed_mean,
     compression_ratio,
 )
+from repro.engine.context import ExecutionContext  # noqa: E402
 
 
 def check_alg3_numerics():
@@ -242,16 +243,16 @@ def check_alg3_pallas_local():
     x = random_tensor(jax.random.PRNGKey(20), dims)
     fs = random_factors(jax.random.PRNGKey(21), dims, rank)
     mesh = make_grid_mesh((2, 2, 2))
+    pallas = ExecutionContext.create(backend="pallas", interpret=True)
     for mode in range(3):
-        f3 = mttkrp_stationary(mesh, mode, 3, backend="pallas",
-                               interpret=True)
+        f3 = mttkrp_stationary(mesh, mode, 3, ctx=pallas)
         xs, fl = place_inputs(mesh, x, fs, mode)
         np.testing.assert_allclose(
             np.asarray(f3(xs, *fl)), np.asarray(mttkrp(x, fs, mode)),
             rtol=1e-4, atol=1e-4,
         )
     mesh4 = make_grid_mesh((2, 2, 1), p0=2)
-    f4 = mttkrp_general(mesh4, 0, 3, backend="pallas", interpret=True)
+    f4 = mttkrp_general(mesh4, 0, 3, ctx=pallas)
     xs, fl = place_inputs(mesh4, x, fs, 0, rank_axis=True)
     np.testing.assert_allclose(
         np.asarray(f4(xs, *fl)), np.asarray(mttkrp(x, fs, 0)),
@@ -272,7 +273,8 @@ def check_cp_sweep_matches_sequential():
     # 12 sweeps: from this seed's initial factors the sequential driver
     # is still climbing at sweep 8 (fit 0.94) and converges by sweep 12
     par = cp_als_parallel(
-        x, rank, n_iters=12, key=jax.random.PRNGKey(31), grid=(2, 2, 2)
+        x, rank, n_iters=12, key=jax.random.PRNGKey(31),
+        ctx=ExecutionContext.create(grid=(2, 2, 2)),
     )
     seq = cp_als(x, rank, n_iters=12, key=jax.random.PRNGKey(31))
     for fp, fs_ in zip(par.fits, seq.fits):
@@ -345,7 +347,6 @@ def check_ring_overlap_sweep():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.tensor import frob_norm
-    from repro.engine.context import ExecutionContext
 
     dims, rank = (32, 32, 32), 4
     x = random_tensor(jax.random.PRNGKey(60), dims)
@@ -357,7 +358,7 @@ def check_ring_overlap_sweep():
         ctx_ring = ExecutionContext.create(grid=grid, overlap="ring")
         # numerics: ring sweep == plain sweep (fp reordering tolerance)
         r_none = cp_als_parallel(x, rank, n_iters=4, init_factors=fs,
-                                 grid=grid)
+                                 ctx=ExecutionContext.create(grid=grid))
         r_ring = cp_als_parallel(x, rank, n_iters=4, init_factors=fs,
                                  ctx=ctx_ring)
         for k in range(3):
@@ -410,7 +411,7 @@ def check_cp_auto_grid_driver():
     # swamp (the sequential driver too stalls at fit 0.77 for 25 sweeps)
     x, _ = random_low_rank_tensor(jax.random.PRNGKey(41), dims, rank)
     res = cp_als(x, rank, n_iters=25, key=jax.random.PRNGKey(2),
-                 distributed=True)
+                 ctx=ExecutionContext.create(distributed=True))
     assert res.final_fit > 0.999, res.fits
     recon = tensor_from_factors(res.factors, res.weights)
     assert float(relative_error(x, recon)) < 0.02
@@ -426,8 +427,8 @@ def check_cp_sweep_pallas_local():
     dims, rank = (16, 16, 24), 4
     x, _ = random_low_rank_tensor(jax.random.PRNGKey(36), dims, rank)
     par = cp_als_parallel(
-        x, rank, n_iters=5, key=jax.random.PRNGKey(37), grid=(2, 2, 2),
-        backend="pallas", interpret=True,
+        x, rank, n_iters=5, key=jax.random.PRNGKey(37),
+        ctx=ExecutionContext.create(grid=(2, 2, 2), backend="pallas", interpret=True),
     )
     seq = cp_als(x, rank, n_iters=5, key=jax.random.PRNGKey(37))
     for fp, fs_ in zip(par.fits, seq.fits):
@@ -447,7 +448,6 @@ def check_context_roundtrip_reproduces_sweep():
 
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro import ExecutionContext
     from repro.core.tensor import frob_norm
     from repro.observe.metrics import PALLAS_DISPATCHES, registry
 
@@ -584,7 +584,6 @@ def check_tucker_parallel_matches_sequential():
     from repro.core.tucker import tucker_hooi
     from repro.distributed.grid_select import choose_tucker_grid
     from repro.distributed.tucker_parallel import tucker_hooi_parallel
-    from repro.engine.context import ExecutionContext
 
     dims, ranks = (16, 16, 16), (4, 3, 2)
     x, _, _ = random_tucker_tensor(jax.random.PRNGKey(53), dims, ranks)
@@ -616,7 +615,6 @@ def check_tucker_sweep_pallas_local():
     per-shard local Multi-TTM: numerics match the einsum-local sweep."""
     from repro.core.tensor import random_tucker_tensor
     from repro.distributed.tucker_parallel import tucker_hooi_parallel
-    from repro.engine.context import ExecutionContext
     from repro.observe.metrics import PALLAS_DISPATCHES, registry
 
     dims, ranks = (16, 16, 24), (4, 3, 2)

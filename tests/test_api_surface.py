@@ -1,14 +1,15 @@
-"""The stable public API surface (`import repro`) and the deprecation shim.
+"""The stable public API surface (`import repro`) and its one way to
+configure an execution (``ctx=``).
 
 Run in the CI fast lane as the API-stability gate: a PR that changes
-``repro.__all__``, drops a docstring, or breaks the one-release
-legacy-kwarg compatibility fails here before anything else.
+``repro.__all__``, drops a docstring, or brings back a per-call option
+keyword fails here before anything else.
 """
 
+import dataclasses
 import warnings
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 import repro
@@ -126,42 +127,74 @@ def test_multi_ttm_surface_is_documented():
 
 
 # ---------------------------------------------------------------------------
-# the deprecated-kwarg shim
+# ctx= is the only configuration argument
 # ---------------------------------------------------------------------------
 
-def test_legacy_kwargs_emit_exactly_one_deprecation_warning():
-    x, fs = _problem()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        repro.mttkrp(x, fs, 0, backend="einsum")
-    dep = [wi for wi in w if wi.category is DeprecationWarning]
-    assert len(dep) == 1, [str(wi.message) for wi in w]
-    msg = str(dep[0].message)
-    # the message must teach the new spelling
-    assert "ExecutionContext.create" in msg
-    assert "ctx=ctx" in msg
-    assert "backend" in msg  # names the offending kwarg(s)
+def _removed_option_cases():
+    """(entry point, positional args, kwargs, expected TypeError text).
+
+    The positional arguments are never used: the call must be refused
+    when it binds, before any computation."""
+    from repro.core import dimension_tree as core_tree
+    from repro.distributed.cp_als_parallel import (
+        build_cp_sweep,
+        cp_als_parallel,
+    )
+    from repro.distributed.mttkrp_parallel import (
+        engine_local_fn,
+        mttkrp_general,
+        mttkrp_stationary,
+    )
+    from repro.engine import execute, tree
+
+    engine4 = ("backend", "memory", "interpret", "tune")
+    table = [
+        ("mttkrp", execute.mttkrp, (None, None, 0), engine4),
+        ("contract_partial", execute.contract_partial,
+         (None, None, (0,), (0,), False), engine4),
+        ("tree.all_mode_mttkrp", tree.all_mode_mttkrp, (None, None),
+         engine4),
+        ("tree.dimtree_als_sweep", tree.dimtree_als_sweep,
+         (None, None, None), engine4),
+        ("cp_als", repro.cp_als, (None, 2),
+         engine4 + ("distributed", "mesh", "grid", "procs",
+                    "use_dimension_tree")),
+        ("cp_gradient", repro.cp_gradient, (None, 2), engine4),
+        ("core.all_mode_mttkrp_dimtree", core_tree.all_mode_mttkrp_dimtree,
+         (None, None), ("backend", "memory", "interpret")),
+        ("core.dimtree_als_sweep", core_tree.dimtree_als_sweep,
+         (None, None, None), ("backend", "memory", "interpret")),
+        ("engine_local_fn", engine_local_fn, (),
+         ("backend", "memory", "interpret")),
+        ("mttkrp_stationary", mttkrp_stationary, (None, 0, 3),
+         ("backend", "interpret")),
+        ("mttkrp_general", mttkrp_general, (None, 0, 3),
+         ("backend", "interpret")),
+        ("build_cp_sweep", build_cp_sweep, (None, 3),
+         ("backend", "interpret", "memory")),
+        ("cp_als_parallel", cp_als_parallel, (None, 2),
+         ("backend", "interpret", "memory", "grid", "mesh", "procs")),
+    ]
+    cases = [
+        pytest.param(
+            fn, args, {kw: True}, f"unexpected keyword argument '{kw}'",
+            id=f"{name}-{kw}",
+        )
+        for name, fn, args, kws in table
+        for kw in kws
+    ]
+    # the old positional spelling engine_local_fn("einsum")
+    cases.append(pytest.param(
+        engine_local_fn, ("einsum",), {}, "positional argument",
+        id="engine_local_fn-positional-backend",
+    ))
+    return cases
 
 
-@pytest.mark.parametrize(
-    "call",
-    [
-        lambda x, fs: repro.cp_als(x, 2, n_iters=1, backend="einsum"),
-        lambda x, fs: repro.cp_gradient(x, 2, n_iters=1, backend="einsum"),
-        lambda x, fs: repro.contract_partial(
-            x, fs, (0, 1, 2), (2,), False, backend="einsum"
-        ),
-    ],
-    ids=["cp_als", "cp_gradient", "contract_partial"],
-)
-def test_every_driver_shims_legacy_kwargs(call):
-    x, fs = _problem()
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        call(x, fs)
-    dep = [wi for wi in w if wi.category is DeprecationWarning]
-    assert len(dep) == 1
-    assert "ExecutionContext" in str(dep[0].message)
+@pytest.mark.parametrize("fn,args,kwargs,match", _removed_option_cases())
+def test_removed_option_kwargs_raise_typeerror(fn, args, kwargs, match):
+    with pytest.raises(TypeError, match=match):
+        fn(*args, **kwargs)
 
 
 def test_ctx_path_is_warning_free():
@@ -182,23 +215,6 @@ def test_default_call_is_warning_free():
         repro.cp_als(x, 2, n_iters=1)
 
 
-def test_ctx_plus_legacy_kwargs_rejected():
-    x, fs = _problem()
-    ctx = repro.ExecutionContext.create()
-    with pytest.raises(TypeError, match="not both"):
-        repro.mttkrp(x, fs, 0, ctx=ctx, backend="einsum")
-
-
-def test_legacy_and_ctx_paths_agree():
-    x, fs = _problem()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = repro.mttkrp(x, fs, 0, backend="blocked_host")
-    ctx = repro.ExecutionContext.create(backend="blocked_host")
-    new = repro.mttkrp(x, fs, 0, ctx=ctx)
-    assert jnp.allclose(legacy, new)
-
-
 # ---------------------------------------------------------------------------
 # the unified validator (one error catalog, actionable messages)
 # ---------------------------------------------------------------------------
@@ -212,10 +228,12 @@ def test_unknown_backend_lists_valid_values():
 
 
 def test_driver_and_context_raise_the_same_backend_error():
-    x, fs = _problem()
+    """A driver only derives contexts (``dataclasses.replace``, as
+    ``ctx.local()`` does); a derived context validates like a created
+    one, so no driver can run an unknown backend or word its error
+    differently."""
     with pytest.raises(ValueError) as via_ctx:
         repro.ExecutionContext.create(backend="nope")
-    with warnings.catch_warnings(), pytest.raises(ValueError) as via_driver:
-        warnings.simplefilter("ignore", DeprecationWarning)
-        repro.mttkrp(x, fs, 0, backend="nope")
+    with pytest.raises(ValueError) as via_driver:
+        dataclasses.replace(repro.ExecutionContext.create(), backend="nope")
     assert str(via_ctx.value) == str(via_driver.value)

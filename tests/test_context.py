@@ -331,34 +331,6 @@ def test_default_is_memoized(monkeypatch):
     assert seeded == ctx and ExecutionContext.default() is seeded
 
 
-def test_engine_local_fn_rejects_ctx_plus_kwargs():
-    from repro.distributed.mttkrp_parallel import engine_local_fn
-
-    with pytest.raises(TypeError, match="not both"):
-        engine_local_fn(ExecutionContext.create(), interpret=True)
-
-
-def test_engine_local_fn_legacy_spellings_shim():
-    """Both old spellings — positional string and backend= keyword —
-    route through the deprecation shim."""
-    import warnings
-
-    from repro.distributed.mttkrp_parallel import engine_local_fn
-
-    x, fs = _problem()
-    for call in (
-        lambda: engine_local_fn("einsum", True),
-        lambda: engine_local_fn(backend="einsum", interpret=True),
-    ):
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            fn = call()
-        assert sum(
-            wi.category is DeprecationWarning for wi in w
-        ) == 1, [str(wi.message) for wi in w]
-        assert fn(x, fs, 0).shape == (8, 3)
-
-
 def test_tree_path_honors_out_dtype_policy():
     """contract_partial applies ctx.out_dtype like the plain path: the
     dimension-tree leaves come out in the policy dtype."""

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import ExecutionContext
 from repro.core.cp_als import cp_als, cp_gradient
 from repro.core.krp import mttkrp_via_matmul
 from repro.core.mttkrp import mttkrp
@@ -58,7 +59,7 @@ def test_als_dimension_tree_matches_plain():
     x, _ = random_low_rank_tensor(jax.random.PRNGKey(4), (8, 7, 6, 5), 2)
     plain = cp_als(x, 2, n_iters=8, key=jax.random.PRNGKey(5))
     tree = cp_als(
-        x, 2, n_iters=8, key=jax.random.PRNGKey(5), use_dimension_tree=True
+        x, 2, n_iters=8, key=jax.random.PRNGKey(5), sweep="dimtree"
     )
     for a, b in zip(plain.fits, tree.fits):
         assert abs(a - b) < 5e-3
@@ -82,12 +83,14 @@ def test_distributed_path_rejects_unsupported_combinations():
     options the sweep driver cannot honor, instead of silently ignoring
     them."""
     x = jnp.zeros((4, 4, 4))
+    dist = ExecutionContext.create(distributed=True)
     with pytest.raises(ValueError, match="mttkrp_fn"):
-        cp_als(x, 2, distributed=True, mttkrp_fn=mttkrp)
-    with pytest.raises(ValueError, match="use_dimension_tree"):
-        cp_als(x, 2, distributed=True, use_dimension_tree=True)
+        cp_als(x, 2, ctx=dist, mttkrp_fn=mttkrp)
+    with pytest.raises(ValueError, match="not supported on the distributed"):
+        cp_als(x, 2, ctx=dist, sweep="dimtree")
     with pytest.raises(ValueError, match="tune=True is not supported"):
-        cp_als(x, 2, distributed=True, backend="auto", tune=True)
+        cp_als(x, 2, ctx=ExecutionContext.create(
+            distributed=True, backend="auto", tune=True))
 
 
 def test_gradient_engine_parity():
@@ -99,7 +102,7 @@ def test_gradient_engine_parity():
     ein = cp_gradient(x, 2, n_iters=30, lr=0.05, key=jax.random.PRNGKey(31))
     pal = cp_gradient(
         x, 2, n_iters=30, lr=0.05, key=jax.random.PRNGKey(31),
-        backend="pallas", interpret=True,
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
     )
     assert len(ein.fits) == len(pal.fits) > 0
     for a, b in zip(ein.fits, pal.fits):

@@ -18,6 +18,7 @@ from repro.core.mttkrp import mttkrp as einsum_mttkrp
 from repro.core.mttkrp import mttkrp_naive
 from repro.engine import (
     BlockPlan,
+    ExecutionContext,
     Memory,
     all_mode_mttkrp,
     best_uniform_block,
@@ -120,7 +121,10 @@ def test_rank_augmented_working_set():
 def test_backends_agree(dims, backend):
     x, fs = _mk(dims, 4, seed=1)
     for mode in range(len(dims)):
-        out = mttkrp(x, fs, mode, backend=backend, interpret=True)
+        out = mttkrp(
+            x, fs, mode,
+            ctx=ExecutionContext.create(backend=backend, interpret=True),
+        )
         np.testing.assert_allclose(
             out, mttkrp_ref(x, fs, mode), rtol=3e-4, atol=3e-4
         )
@@ -129,7 +133,7 @@ def test_backends_agree(dims, backend):
 def test_unknown_backend_rejected():
     x, fs = _mk((4, 4, 4), 2)
     with pytest.raises(ValueError):
-        mttkrp(x, fs, 0, backend="cuda")
+        mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="cuda"))
 
 
 # --------------------------------------------------------------------------
@@ -144,7 +148,9 @@ def test_mttkrpn_4way_5way_vs_naive(dims):
     including non-divisible shapes that exercise the padding path."""
     x, fs = _mk(dims, 5, seed=2)
     for mode in range(len(dims)):
-        out = mttkrp(x, fs, mode, backend="pallas", interpret=True)
+        out = mttkrp(
+            x, fs, mode, ctx=ExecutionContext.create(backend="pallas", interpret=True)
+        )
         ref = mttkrp_naive(x, fs, mode)
         np.testing.assert_allclose(out, ref, rtol=5e-4, atol=5e-4)
 
@@ -155,7 +161,10 @@ def test_explicit_plan_padding_path():
     dims = (10, 6, 11, 3)
     x, fs = _mk(dims, 7, seed=3)
     plan = BlockPlan(8, (8, 128, 8), 128)
-    out = mttkrp(x, fs, 2, backend="pallas", plan=plan, interpret=True)
+    out = mttkrp(
+        x, fs, 2, plan=plan,
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
+    )
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 2), rtol=5e-4, atol=5e-4
     )
@@ -172,7 +181,6 @@ def test_tensor_relayouts_count_what_reaches_x(dims, labels):
     for an aligned 3-way tensor (every mode reads X in place), one pad a
     mode for an unaligned one, a transpose a mode for a 4-way tensor's
     modes 1..3 (the generic kernel reads a mode-first copy)."""
-    from repro import ExecutionContext
     from repro.observe import Trace
 
     x, _ = _mk(dims, 3, seed=6)
@@ -196,8 +204,10 @@ def test_tensor_relayouts_count_what_reaches_x(dims, labels):
 def test_dimtree_pallas_all_modes(dims):
     x, fs = _mk(dims, 4, seed=4)
     before = _dispatches()
-    outs = all_mode_mttkrp(x, fs, method="dimtree", backend="pallas",
-                           interpret=True)
+    outs = all_mode_mttkrp(
+        x, fs, method="dimtree",
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
+    )
     # every tree edge must have gone through the kernels
     assert _dispatches() - before >= 2 * (len(dims) - 1)
     for mode in range(len(dims)):
@@ -218,7 +228,10 @@ def test_dimtree_pallas_sweep_gauss_seidel_order():
         return fs[mode] * 1.1
 
     fs_tree = [f + 0 for f in fs]
-    dimtree_als_sweep(x, fs_tree, update, backend="pallas", interpret=True)
+    dimtree_als_sweep(
+        x, fs_tree, update,
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
+    )
     cur = [f + 0 for f in fs]
     for mode in range(len(dims)):
         expected = einsum_mttkrp(x, cur, mode)
@@ -234,8 +247,8 @@ def test_cp_als_dimtree_pallas_matches_plain():
     plain = cp_als(x, 2, n_iters=6, init_factors=fs)
     before = _dispatches()
     tree = cp_als(
-        x, 2, n_iters=6, init_factors=fs, use_dimension_tree=True,
-        backend="pallas", interpret=True,
+        x, 2, n_iters=6, init_factors=fs, sweep="dimtree",
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
     )
     assert _dispatches() > before  # kernel path taken
     for a, b in zip(plain.fits, tree.fits):

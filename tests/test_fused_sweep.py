@@ -35,7 +35,7 @@ def _mk(dims, rank, seed=0, dtype=jnp.float32):
 def _pair_oracle(x, factors):
     """B0 (full MTTKRP mode 0) and P' = X x_{N-1} A_{N-1} via einsum."""
     n = x.ndim
-    b0 = mttkrp(x, factors, 0, backend="einsum")
+    b0 = mttkrp(x, factors, 0, ctx=ExecutionContext.create(backend="einsum"))
     letters = "abcdefg"[:n]
     p = jnp.einsum(
         f"{letters},{letters[-1]}r->{letters[:-1]}r", x, factors[n - 1]
@@ -209,22 +209,9 @@ def test_cp_als_sweep_knob_validation():
     x, _ = _mk((8, 8, 8), 3)
     with pytest.raises(ValueError, match="unknown sweep"):
         cp_als(x, 3, n_iters=1, sweep="bogus")
-    with pytest.raises(ValueError, match="use_dimension_tree"):
-        cp_als(x, 3, n_iters=1, sweep="fused", use_dimension_tree=True)
     ctx = ExecutionContext.create(distributed=True, procs=1)
     with pytest.raises(ValueError, match="distributed"):
         cp_als(x, 3, n_iters=1, sweep="fused", ctx=ctx)
-
-
-def test_cp_als_sweep_dimtree_alias():
-    """sweep="dimtree" is the explicit spelling of use_dimension_tree."""
-    dims, rank = (12, 12, 12), 3
-    x, _ = random_low_rank_tensor(jax.random.PRNGKey(8), dims, rank)
-    key = jax.random.PRNGKey(9)
-    a = cp_als(x, rank, n_iters=4, key=key, use_dimension_tree=True)
-    b = cp_als(x, rank, n_iters=4, key=key, sweep="dimtree")
-    for fa, fb in zip(a.fits, b.fits):
-        assert abs(fa - fb) < 1e-6
 
 
 # ---------------------------------------------------------------------------

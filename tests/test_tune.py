@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.engine import execute
+from repro.engine import ExecutionContext, execute
 from repro.engine.plan import BlockPlan, Memory, choose_blocks
 from repro.kernels.ref import mttkrp_ref
 from repro.tune.cache import (
@@ -122,7 +122,7 @@ def test_corrupted_cache_falls_back_to_analytic(tmp_path, monkeypatch):
         f.write("garbage")
     monkeypatch.setenv("REPRO_TUNE_CACHE", path)
     x, fs = _problem()
-    out = execute.mttkrp(x, fs, 0, backend="auto")  # must not raise
+    out = execute.mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="auto"))  # must not raise
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
     )
@@ -176,7 +176,8 @@ def test_degenerate_plans_run_correctly(dims, rank):
     x, fs = _problem(dims, rank)
     plan = choose_blocks(dims, rank)
     out = execute.mttkrp(
-        x, fs, 0, backend="pallas", plan=plan, interpret=True
+        x, fs, 0, plan=plan,
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
     )
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
@@ -216,7 +217,8 @@ def test_search_winner_is_fastest_measured(tuned_env):
 def test_kernel_variant_generic_on_3way_correct():
     x, fs = _problem()
     out = execute.mttkrp(
-        x, fs, 0, backend="pallas", kernel_variant="generic", interpret=True
+        x, fs, 0, kernel_variant="generic",
+        ctx=ExecutionContext.create(backend="pallas", interpret=True),
     )
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
@@ -231,7 +233,7 @@ def test_auto_cold_falls_back_to_model_best(tuned_env):
     x, fs = _problem()
     r = resolve(x.shape, 4, 0, x.dtype, None)
     assert not r.cache_hit
-    out = execute.mttkrp(x, fs, 0, backend="auto")
+    out = execute.mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="auto"))
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
     )
@@ -256,7 +258,7 @@ def test_auto_tune_persists_and_replays_exactly(tuned_env):
     entry = fresh.get(r.key)
     assert entry is not None and entry.backend == res.winner.backend
     assert entry.to_plan() == res.winner.plan
-    out = execute.mttkrp(x, fs, 0, backend="auto")
+    out = execute.mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="auto"))
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
     )
@@ -264,7 +266,9 @@ def test_auto_tune_persists_and_replays_exactly(tuned_env):
 
 def test_auto_via_execute_tune_flag(tuned_env):
     x, fs = _problem((12, 10, 8), 3)
-    out = execute.mttkrp(x, fs, 0, backend="auto", tune=True)
+    out = execute.mttkrp(
+        x, fs, 0, ctx=ExecutionContext.create(backend="auto", tune=True)
+    )
     np.testing.assert_allclose(
         out, mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
     )
@@ -278,7 +282,9 @@ def test_auto_is_trace_safe(tuned_env):
 
     @jax.jit
     def f(x, fs):
-        return execute.mttkrp(x, tuple(fs), 0, backend="auto", tune=True)
+        return execute.mttkrp(
+            x, tuple(fs), 0, ctx=ExecutionContext.create(backend="auto", tune=True)
+        )
 
     np.testing.assert_allclose(
         f(x, fs), mttkrp_ref(x, fs, 0), rtol=5e-4, atol=5e-4
@@ -291,13 +297,17 @@ def test_auto_in_dimtree_and_cp_als(tuned_env):
     from repro.engine.tree import all_mode_mttkrp
 
     x, fs = _problem()
-    outs = all_mode_mttkrp(x, fs, method="dimtree", backend="auto")
+    outs = all_mode_mttkrp(
+        x, fs, method="dimtree", ctx=ExecutionContext.create(backend="auto")
+    )
     for m, b in enumerate(outs):
         np.testing.assert_allclose(
             b, mttkrp_ref(x, fs, m), rtol=5e-4, atol=5e-4
         )
     xt, _ = random_low_rank_tensor(jax.random.PRNGKey(0), (12, 10, 8), 3)
-    res = cp_als(xt, 3, n_iters=8, backend="auto", tune=True)
+    res = cp_als(
+        xt, 3, n_iters=8, ctx=ExecutionContext.create(backend="auto", tune=True)
+    )
     assert res.final_fit > 0.8
 
 
@@ -332,29 +342,29 @@ def test_tune_partial_persists_and_replays(tuned_env):
 
 
 def test_dimtree_auto_tune_writes_partial_entries(tuned_env):
-    """cp_als(backend="auto", tune=True, use_dimension_tree=True) must
+    """cp_als(sweep="dimtree") under an "auto" + tune context must
     actually tune the tree edges, not silently cache nothing."""
     from repro.core.cp_als import cp_als
     from repro.core.tensor import random_low_rank_tensor
 
     xt, _ = random_low_rank_tensor(jax.random.PRNGKey(0), (12, 10, 8), 3)
-    res = cp_als(xt, 3, n_iters=4, backend="auto", tune=True,
-                 use_dimension_tree=True)
+    res = cp_als(xt, 3, n_iters=4, sweep="dimtree",
+                 ctx=ExecutionContext.create(backend="auto", tune=True))
     assert res.final_fit > 0.8
     partial_keys = [
         k for k in PlanCache(tuned_env).keys() if k.startswith("partial|")
     ]
     assert partial_keys  # the sweep persisted tuned tree edges
     # and the warm sweep replays them (resolve hits, same fit path)
-    res2 = cp_als(xt, 3, n_iters=4, backend="auto",
-                  use_dimension_tree=True)
+    res2 = cp_als(xt, 3, n_iters=4, sweep="dimtree",
+                  ctx=ExecutionContext.create(backend="auto"))
     assert res2.final_fit == pytest.approx(res.final_fit, abs=1e-6)
 
 
 def test_unknown_backend_message_mentions_auto():
     x, fs = _problem()
     with pytest.raises(ValueError, match="auto"):
-        execute.mttkrp(x, fs, 0, backend="nope")
+        execute.mttkrp(x, fs, 0, ctx=ExecutionContext.create(backend="nope"))
 
 
 # ---------------------------------------------------------------------------
